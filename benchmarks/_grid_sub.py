@@ -5,7 +5,8 @@ import sys
 
 from repro.util import env
 
-env.force_host_device_count(int(sys.argv[1]))   # before any jax import
+# before any jax import
+env.configure(host_device_count=int(sys.argv[1]), platform="cpu")
 
 import jax  # noqa: E402
 

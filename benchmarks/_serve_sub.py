@@ -19,7 +19,7 @@ import sys
 
 from repro.util import env
 
-env.force_host_device_count(8)   # before any jax import
+env.configure(host_device_count=8, platform="cpu")   # before any jax import
 
 import threading  # noqa: E402
 import time  # noqa: E402

@@ -2,7 +2,7 @@ import os
 
 from repro.util import env
 
-env.force_host_device_count(512)
+env.configure(host_device_count=512, platform="cpu")
 # (must precede any jax import — same rule as the dry-run)
 
 """§Perf hillclimb driver: hypothesis → change → re-lower → measure, on the

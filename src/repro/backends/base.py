@@ -42,10 +42,63 @@ schedule × backend matrix for free (modulo representation support).
 
 from __future__ import annotations
 
+import copy
 from typing import Callable, Type, Union
 
 import jax
 import jax.numpy as jnp
+
+
+#: The precision of every fp32 product the program runs, decided here
+#: once: on TPU the default rounds fp32 operands to bf16, which moves the
+#: relative error and the BPP and top-k sign tests.  On CPU it changes
+#: nothing.
+PRECISION = jax.lax.Precision.HIGHEST
+
+#: Dims of ``dot``'s operands it contracts: a·b and aᵀ·b.
+AB, ATB = ((1,), (0,)), ((0,), (0,))
+
+
+def dot(a, b, contract=AB):
+    """a·b over the ``contract`` dims (``AB``, ``ATB``, or any pair) at
+    ``PRECISION``, accumulated and returned in fp32 whatever the operand
+    dtypes (bf16 panels included).  Works inside Pallas kernels too."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=PRECISION,
+                               preferred_element_type=jnp.float32)
+
+
+#: Longest contraction one fp32 dot runs before its partial product is
+#: added to an fp32 accumulator.  XLA's fp32 dot on TPU drifts low over long
+#: contractions: at HIGHEST precision, AᵀW over 126,675 rows came out
+#: 1.6e-5 low on average on a TPU v5e (1e-4 on the trace-trick rel error);
+#: summed in 8,192-row blocks the drift was 5e-7.
+ROW_BLOCK = 8192
+
+
+def contract_rows(A, B, row_block: int | None = ROW_BLOCK):
+    """Aᵀ·B (fp32, ``PRECISION``) for A (m, n), B (m, k), contracting the
+    rows in blocks of ``row_block`` (None: one dot).  The blocks are dynamic
+    slices the compiler fuses into the dot, so A is never copied; the last
+    block is shifted back to end at row m, its repeated rows zeroed in B.
+
+    For device-local operands only: under XLA's SPMD partitioner a dynamic
+    slice along a sharded dimension gathers that dimension, so global-view
+    programs pass ``row_block=None`` (``LocalOps.global_view_ops``)."""
+    m = A.shape[0]
+    if row_block is None or m <= row_block:
+        return dot(A, B, ATB)
+
+    def block(i, acc):
+        start = jnp.minimum(i * row_block, m - row_block)
+        a = jax.lax.dynamic_slice_in_dim(A, start, row_block, 0)
+        b = jax.lax.dynamic_slice_in_dim(B, start, row_block, 0)
+        fresh = start + jnp.arange(row_block) >= i * row_block
+        return acc + dot(a, jnp.where(fresh[:, None], b, jnp.zeros_like(b)),
+                         ATB)
+
+    out = jnp.zeros((A.shape[1], B.shape[1]), jnp.float32)
+    return jax.lax.fori_loop(0, -(-m // row_block), block, out)
 
 
 class LocalOps:
@@ -70,6 +123,11 @@ class LocalOps:
     #: gspmd on a single device only (shard_map schedules are unaffected)
     partitionable: bool = True
 
+    #: row block of the fp32 contractions over a panel's rows (``gram``,
+    #: and ``DenseOps.mm_t``; see ``contract_rows``); None in the
+    #: global-view variant
+    row_block: int | None = ROW_BLOCK
+
     # -- the three local products ------------------------------------------
 
     def mm(self, A, B):
@@ -82,10 +140,10 @@ class LocalOps:
 
     def gram(self, X):
         """Xᵀ X for a tall-skinny factor panel X (r, k) -> (k, k) fp32.
-        Factor panels are dense on every backend (only A's storage varies)."""
-        return jax.lax.dot_general(
-            X, X, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        Factor panels are dense on every backend (only A's storage varies).
+        Rows are summed in blocks (``contract_rows``): the Gram enters
+        every update and the error."""
+        return contract_rows(X, X, self.row_block)
 
     # -- representation hooks ----------------------------------------------
 
@@ -159,8 +217,14 @@ class LocalOps:
     def global_view_ops(self) -> "LocalOps":
         """The variant of this backend safe for global-view (gspmd)
         programs, where XLA's auto-partitioner owns the parallelism and
-        cannot partition hand-written kernels.  Default: self."""
-        return self
+        cannot partition hand-written kernels, and gathers any dimension a
+        dynamic slice walks along.  Default: this backend with its row
+        contractions in one dot (``row_block=None``)."""
+        if self.row_block is None:
+            return self
+        ops = copy.copy(self)
+        ops.row_block = None
+        return ops
 
     # -- cost-model hook ----------------------------------------------------
 
@@ -187,7 +251,7 @@ class LocalOps:
         custom backends should extend this with their configuration.  Keyed
         on the concrete class OBJECT (not its name) so re-registering a
         redefined class under the same name invalidates cached runs."""
-        return (type(self), self.name)
+        return (type(self), self.name, self.row_block)
 
     # -- helpers ------------------------------------------------------------
 

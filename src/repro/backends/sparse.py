@@ -63,10 +63,11 @@ class SparseOps(LocalOps):
     def global_view_ops(self) -> "SparseOps":
         """Under the gspmd auto-partitioner only the XLA scatter-add is
         partitionable (a pallas_call would pin the nnz-sharded triplets to
-        one device), so force impl="scatter" for global-view programs."""
-        if self.spmm_impl == "scatter":
-            return self
-        return SparseOps(spmm_impl="scatter")
+        one device), so force impl="scatter" for global-view programs; the
+        factor Grams contract in one dot there (``LocalOps``)."""
+        ops = self if self.spmm_impl == "scatter" else SparseOps(
+            spmm_impl="scatter")
+        return LocalOps.global_view_ops(ops)
 
     def _impl(self, A=None, need: str = "both") -> str:
         """Effective impl for one product.  ``need`` names the sorted-layout
@@ -193,6 +194,7 @@ class SparseOps(LocalOps):
             mb, nb = m // gr, n // gc
             a = self.align
             U = max(-(-nnz_max // a), 1)
+            unit = blocksparse.unit_rows_for(nnz_max, mb, nb, a)
             L = U * a
             extra = dict(
                 row_offsets=sds((gr, gc, mb + 1), jnp.int32),
@@ -204,7 +206,7 @@ class SparseOps(LocalOps):
                 col_offsets=sds((gr, gc, nb + 1), jnp.int32),
                 col_tiles=sds((gr, gc, U), jnp.int32),
                 col_valid=sds((gr, gc, U), jnp.int32),
-                align=a)
+                align=a, unit_rows=unit)
             nnz_max = L
         return blocksparse.BlockCOO(
             vals=sds((gr, gc, nnz_max), dtype),

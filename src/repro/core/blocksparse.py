@@ -26,8 +26,9 @@ blockify time and records three per-block index arrays per orientation:
 
   * ``row_offsets`` (mb+1,)  CSR-style prefix counts — offsets of each
     row's triplet segment in the *unpadded* sorted order;
-  * ``row_tiles``   (U,)     the 8-row output tile each ``align``-sized
-    packed unit of triplets belongs to;
+  * ``row_tiles``   (U,)     the first 8-row output tile of the row unit
+    (``unit_rows`` rows) each ``align``-sized packed unit of triplets
+    belongs to;
   * ``row_valid``   (U,)     how many triplets of each unit are real
     (the rest are zero-padding no-ops).
 
@@ -35,10 +36,13 @@ plus a transposed copy (``t_vals``/``t_rows``/``t_cols`` with
 ``col_offsets``/``col_tiles``/``col_valid``) holding the same nonzeros
 sorted by column, so Aᵀ·B runs through the *same* sorted kernel with the
 (rows ↔ cols) swap trick and Aᵀ is never materialised.  The packed layout
-pads each 8-row tile's segment to a multiple of ``align`` so a kernel nnz
+pads each row unit's segment to a multiple of ``align`` so a kernel nnz
 chunk never spans two output tiles — that is what lets kernels/spmm.py
 stream output rows through a small accumulator tile with scalar prefetch
-instead of pinning the whole (m_blk, k) output in VMEM.
+instead of pinning the whole (m_blk, k) output in VMEM.  A row unit is
+``unit_rows`` rows, 8 or a power-of-two multiple of 8 chosen at sort time
+so that a unit holds about ``align`` nonzeros or more: the padding stays a
+fraction of the data even when ``align`` is the compiled kernel's 1,024.
 """
 
 from __future__ import annotations
@@ -52,15 +56,34 @@ import numpy as np
 
 
 #: Default packed-segment alignment for ``sort_rows`` (triplets per unit).
-#: The sorted kernel's nnz chunk size must divide it; 64 keeps the
-#: interpret-mode loops small while still giving the autotuner headroom
-#: (on real TPUs pass a larger align, e.g. 512, at sort time).
-DEFAULT_ALIGN = 64
+#: The sorted kernel's nnz chunk size must divide it, and the compiled
+#: kernel streams chunks of a multiple of 1024 (XLA's tile of a 1-D array
+#: in SMEM); interpret-mode tests pass smaller aligns to keep loops cheap.
+DEFAULT_ALIGN = 1024
 
-#: Output-row granularity of the sorted layout: segments are tile-aligned
-#: per ROW_TILE rows so any accumulator height that is a multiple of it
-#: (the fp32 sublane count) keeps chunks inside one output tile.
+#: Least output-row granularity of the sorted layout (the fp32 sublane
+#: count): segments are aligned per row unit of ``unit_rows`` rows, a
+#: power-of-two multiple of ROW_TILE, so any accumulator height that is a
+#: multiple of the unit keeps chunks inside one output tile.
 ROW_TILE = 8
+
+#: Most rows one unit may span (the sorted kernel's accumulator tile is at
+#: least a unit tall: 1,024 × 128 fp32 is 512 KiB of VMEM).
+MAX_UNIT_ROWS = 1024
+
+
+def unit_rows_for(nnz: float, mb: int, nb: int, align: int) -> int:
+    """Rows per packed unit for a block of ``nnz`` nonzeros over (mb, nb):
+    the least ROW_TILE·2^j (≤ MAX_UNIT_ROWS) whose mean count in the
+    sparser orientation reaches ``align``.  At align 1,024 the 43,200 ×
+    28,800 block at density 1e-3 gets 64-row units, ~1,843 and ~2,765
+    nonzeros a unit in its two orientations, padded to 2,048 and 3,072
+    (1.11×); per 8-row tile (~230 and ~346) it would be 4.4× and 3.0×."""
+    per_line = nnz / max(mb, nb, 1)
+    t = ROW_TILE
+    while t < MAX_UNIT_ROWS and t * per_line < align:
+        t *= 2
+    return t
 
 # Sorted-orientation array-field names (children of the pytree, all rank 3).
 _SORT_FIELDS = ("row_offsets", "row_tiles", "row_valid",
@@ -80,8 +103,9 @@ class BlockCOO:
     After ``sort_rows()`` the triplets are row-sorted in the tile-aligned
     packed layout and the nine optional metadata leaves (see the module
     docstring) are populated; ``align`` records the packing alignment
-    (0 ⇒ unsorted).  All leaves keep the leading (gr, gc) grid dims so one
-    PartitionSpec shards the whole pytree.
+    (0 ⇒ unsorted) and ``unit_rows`` the rows of one packed unit.  All
+    leaves keep the leading (gr, gc) grid dims so one PartitionSpec shards
+    the whole pytree.
     """
 
     vals: Any
@@ -102,6 +126,7 @@ class BlockCOO:
     col_tiles: Any = None
     col_valid: Any = None
     align: int = 0
+    unit_rows: int = ROW_TILE
 
     @property
     def dtype(self):
@@ -128,13 +153,14 @@ class BlockCOO:
     def tree_flatten(self):
         return ((self.vals, self.rows, self.cols)
                 + tuple(getattr(self, f) for f in _SORT_FIELDS),
-                (self.shape, self.block_shape, self.nnz, self.align))
+                (self.shape, self.block_shape, self.nnz, self.align,
+                 self.unit_rows))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        shape, block_shape, nnz, align = aux
+        shape, block_shape, nnz, align, unit_rows = aux
         return cls(*children[:3], shape, block_shape, nnz,
-                   *children[3:], align=align)
+                   *children[3:], align=align, unit_rows=unit_rows)
 
     def sort_rows(self, *, align: int = DEFAULT_ALIGN,
                   orient: str = "both") -> "BlockCOO":
@@ -240,9 +266,21 @@ def blockify(A, gr: int, gc: int) -> BlockCOO:
 
 
 def sq_norm(A: BlockCOO) -> jax.Array:
-    """||A||_F² in fp32 (padding values are exact zeros)."""
+    """||A||_F² in fp32, the same bits for every layout of the same matrix.
+
+    Squares are first summed per row of each block, in stored order, then
+    over the fixed-length row vector.  Padding adds exact zeros, and
+    ``sort_rows`` is a stable sort, so a row's nonzeros meet in the same
+    order in the unsorted and sorted layouts; a flat sum over the stored
+    triplets would group them by position and differ in the last bit."""
+    gr, gc = A.grid
+    mb = A.block_shape[0]
     v = A.vals.astype(jnp.float32)
-    return jnp.sum(v * v)
+    block = jnp.arange(gr * gc, dtype=jnp.int32).reshape(gr, gc, 1)
+    seg = (block * mb + A.rows).reshape(-1)
+    per_row = jax.ops.segment_sum((v * v).reshape(-1), seg,
+                                  num_segments=gr * gc * mb)
+    return jnp.sum(per_row)
 
 
 def pad_nnz(blk: BlockCOO, multiple: int) -> BlockCOO:
@@ -265,21 +303,23 @@ def pad_nnz(blk: BlockCOO, multiple: int) -> BlockCOO:
 # Row sorting — the locality-optimized layout for kernels/spmm.spmm_sorted.
 # ---------------------------------------------------------------------------
 
-def _sorted_layout(vals, rows, cols, dim: int, align: int):
-    """Sort ONE block's triplets by ``rows`` and pack them per 8-row output
-    tile, each tile's segment zero-padded to a multiple of ``align``.
+def _sorted_layout(vals, rows, cols, dim: int, align: int, unit_rows: int):
+    """Sort ONE block's triplets by ``rows`` and pack them per row unit of
+    ``unit_rows`` rows, each unit's segment zero-padded to a multiple of
+    ``align``.
 
     Returns numpy arrays (pv, pr, pc, offsets, tiles, valid): the packed
     triplets (length U·align), CSR prefix offsets over the *unpadded*
-    sorted order (dim+1,), and per-unit tile ids / valid counts (U,).
-    Padding entries are (row = tile's first row, col = 0, val = 0) — no-ops
-    for both the sorted kernel (skipped via ``valid``) and scatter-add.
+    sorted order (dim+1,), and per-unit first 8-row tile ids / valid counts
+    (U,).  Padding entries are (row = the unit's first row, col = 0,
+    val = 0) — no-ops for both the sorted kernel (skipped via ``valid``)
+    and scatter-add.
     """
     order = np.argsort(rows, kind="stable")
     sv, sr, sc = vals[order], rows[order], cols[order]
     offs = np.searchsorted(sr, np.arange(dim + 1)).astype(np.int32)
-    ntiles = -(-dim // ROW_TILE)
-    bounds = np.minimum(np.arange(ntiles + 1) * ROW_TILE, dim)
+    ntiles = -(-dim // unit_rows)
+    bounds = np.minimum(np.arange(ntiles + 1) * unit_rows, dim)
     t_start, t_end = offs[bounds[:-1]], offs[bounds[1:]]
     units = -(-(t_end - t_start) // align)          # 0 ⇒ empty tile, skipped
     U = int(units.sum())
@@ -295,8 +335,8 @@ def _sorted_layout(vals, rows, cols, dim: int, align: int):
         pv[pos:pos + ln] = sv[s:e]
         pr[pos:pos + ln] = sr[s:e]
         pc[pos:pos + ln] = sc[s:e]
-        pr[pos + ln:pos + nu * align] = t * ROW_TILE
-        tiles[u:u + nu] = t
+        pr[pos + ln:pos + nu * align] = t * unit_rows
+        tiles[u:u + nu] = t * (unit_rows // ROW_TILE)
         valid[u:u + nu] = np.minimum(
             np.maximum(ln - np.arange(nu) * align, 0), align)
         u += nu
@@ -346,21 +386,24 @@ def sort_rows(blk: BlockCOO, *, align: int = DEFAULT_ALIGN,
     only one product runs on this copy: "rows" (mm only) skips the
     transposed arrays, "cols" (mm_t only) skips the row re-pack — e.g. the
     naive schedule's row-blocked copy only ever sees mm.  Default "both".
+    The rows of a packed unit come from the block's density
+    (``unit_rows_for``).
     """
     if align <= 0 or align % ROW_TILE:
         raise ValueError(f"align must be a positive multiple of {ROW_TILE}, "
                          f"got {align}")
-    if orient not in ("both", "rows", "cols"):
-        raise ValueError(f"orient must be both|rows|cols, got {orient!r}")
     gr, gc = blk.grid
     mb, nb = blk.block_shape
+    unit_rows = unit_rows_for(blk.nnz / (gr * gc), mb, nb, align)
+    if orient not in ("both", "rows", "cols"):
+        raise ValueError(f"orient must be both|rows|cols, got {orient!r}")
     V = np.asarray(blk.vals).reshape(gr * gc, -1)
     R = np.asarray(blk.rows).reshape(gr * gc, -1)
     C = np.asarray(blk.cols).reshape(gr * gc, -1)
     last_tile = lambda lay: [int(x[4][-1]) if x[4].size else 0 for x in lay]
     kw: dict = {}
     if orient != "cols":
-        row = [_sorted_layout(V[b], R[b], C[b], mb, align)
+        row = [_sorted_layout(V[b], R[b], C[b], mb, align, unit_rows)
                for b in range(gr * gc)]
         (pv, pr, pc, r_tiles, r_valid) = _stack_padded(
             [([x[0] for x in row], False), ([x[1] for x in row], False),
@@ -370,7 +413,8 @@ def sort_rows(blk: BlockCOO, *, align: int = DEFAULT_ALIGN,
                   row_valid=r_valid, row_offsets=jnp.asarray(
                       np.stack([x[3] for x in row]).reshape(gr, gc, -1)))
     if orient != "rows":
-        col = [_sorted_layout(V[b], C[b], R[b], nb, align)   # Aᵀ: cols drive
+        col = [_sorted_layout(V[b], C[b], R[b], nb, align,   # Aᵀ: cols drive
+                              unit_rows)
                for b in range(gr * gc)]
         (tv, tr, tc, c_tiles, c_valid) = _stack_padded(
             [([x[0] for x in col], False), ([x[1] for x in col], False),
@@ -379,7 +423,7 @@ def sort_rows(blk: BlockCOO, *, align: int = DEFAULT_ALIGN,
         kw.update(t_vals=tv, t_rows=tr, t_cols=tc, col_tiles=c_tiles,
                   col_valid=c_valid, col_offsets=jnp.asarray(
                       np.stack([x[3] for x in col]).reshape(gr, gc, -1)))
-    return dataclasses.replace(blk, align=align, **kw)
+    return dataclasses.replace(blk, align=align, unit_rows=unit_rows, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -428,7 +472,8 @@ def local_spmm(blk: BlockCOO, B: jax.Array, *, impl: str = "scatter",
             blk.vals.reshape(-1), blk.rows.reshape(-1),
             blk.cols.reshape(-1), blk.row_offsets.reshape(-1),
             blk.row_tiles.reshape(-1), blk.row_valid.reshape(-1), B,
-            blk.block_shape[0], align=blk.align, autotune=autotune)
+            blk.block_shape[0], align=blk.align, unit_rows=blk.unit_rows,
+            autotune=autotune)
     v, r, c = _local_triplets(blk)
     if impl == "pallas":
         from repro.kernels import ops as kops
@@ -451,7 +496,8 @@ def local_spmm_t(blk: BlockCOO, B: jax.Array, *, impl: str = "scatter",
             blk.t_vals.reshape(-1), blk.t_rows.reshape(-1),
             blk.t_cols.reshape(-1), blk.col_offsets.reshape(-1),
             blk.col_tiles.reshape(-1), blk.col_valid.reshape(-1), B,
-            blk.block_shape[1], align=blk.align, autotune=autotune)
+            blk.block_shape[1], align=blk.align, unit_rows=blk.unit_rows,
+            autotune=autotune)
     v, r, c = _local_triplets(blk)
     if impl == "pallas":
         from repro.kernels import ops as kops

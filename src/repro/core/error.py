@@ -15,7 +15,10 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.jit
 def sq_frobenius(A: jax.Array) -> jax.Array:
+    """‖A‖_F² in fp32, compiled so the squares fuse into the sum: run op by
+    op, ``A * A`` would first materialise a second copy of A."""
     A32 = A.astype(jnp.float32)
     return jnp.sum(A32 * A32)
 

@@ -71,6 +71,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.backends.base import dot
 from repro.core.bpp import solve_bpp
 
 
@@ -98,7 +99,7 @@ def _identity(v):
 
 def update_mu(G: jax.Array, R: jax.Array, X: jax.Array) -> jax.Array:
     """X ← X ⊙ R / (X G + ε)   (paper eq. (3); F = 2rk² flops)."""
-    denom = X @ G + eps_for(X.dtype)
+    denom = dot(X, G).astype(X.dtype) + eps_for(X.dtype)
     return X * (R / denom)
 
 
@@ -130,14 +131,15 @@ def update_hals(G: jax.Array, R: jax.Array, X: jax.Array, *,
     def col(i, X):
         gii = G[i, i]
         if normalize:
-            xi = X[:, i] * gii + R[:, i] - X @ G[:, i]
+            xi = X[:, i] * gii + R[:, i] - dot(X, G[:, i]).astype(X.dtype)
             xi = jnp.maximum(xi, 0.0)
             sq = norm_psum(jnp.sum(jnp.square(xi.astype(jnp.float32))))
             nrm = jnp.sqrt(sq).astype(xi.dtype)
             # Guard the all-zero column (paper's code resets to machine eps).
             xi = jnp.where(nrm > 0, xi / jnp.maximum(nrm, eps), xi)
         else:
-            xi = X[:, i] + (R[:, i] - X @ G[:, i]) / jnp.maximum(gii, eps)
+            xi = X[:, i] + ((R[:, i] - dot(X, G[:, i]).astype(X.dtype))
+                            / jnp.maximum(gii, eps))
             xi = jnp.maximum(xi, 0.0)
         return X.at[:, i].set(xi.astype(X.dtype))
 
@@ -403,8 +405,20 @@ class BPPRule(UpdateRule):
         super().__init__(l1=l1, l2=l2)
         self.max_iter = max_iter
 
+    def init_state(self, m, n, k, dtype=jnp.float32):
+        """Rows of the fit's half-steps that BPP re-solved with a Tikhonov
+        term, and rows it clamped (``core.bpp.ESCALATION``), summed over
+        the grid and the iterations."""
+        del m, n, k, dtype
+        zero = jnp.zeros((), jnp.int32)
+        return {"regularised_rows": zero, "clamped_rows": zero}
+
     def _update_w(self, G, R, X, state, *, norm_psum):
-        return update_bpp(G, R, X, max_iter=self.max_iter), state
+        X, counts = solve_bpp(G, R, max_iter=self.max_iter, counts=True)
+        if not isinstance(state, dict) or not counts.keys() <= state.keys():
+            return X, state     # stateless callers, or a subclass's carry
+        return X, {**state, **{key: state[key] + norm_psum(c)
+                               for key, c in counts.items()}}
 
     _update_h = _update_w
 

@@ -23,6 +23,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.backends.base import dot
+
 
 # ------------------------------------------------------------------ LM data
 
@@ -93,11 +95,21 @@ def lowrank_matrix(key, m, n, k, *, noise: float = 0.0, dtype=jnp.float32):
 def _erdos_renyi_sample(key, m, n, density: float, dtype):
     """The one Erdős–Rényi sampler both storage variants draw from, so the
     same key yields the same matrix in dense and BCOO form by construction
-    (tests assert the round trip)."""
-    k1, k2 = jax.random.split(key)
-    mask = jax.random.bernoulli(k1, density, (m, n))
-    vals = jax.random.uniform(k2, (m, n), dtype)
-    return mask, vals
+    (tests assert the round trip).
+
+    It samples the nonzeros' positions directly, never an (m, n) mask:
+    ``round(density·m·n)`` uniform (row, col) draws on the device, of which
+    repeats and values that round to zero in ``dtype`` are dropped on the
+    host.  Returns row-major sorted host triplets (rows, cols, vals)."""
+    import numpy as np
+    count = int(round(density * m * n))
+    kr, kc, kv = jax.random.split(key, 3)
+    rows = np.asarray(jax.random.randint(kr, (count,), 0, m), np.int64)
+    cols = np.asarray(jax.random.randint(kc, (count,), 0, n), np.int64)
+    vals = np.asarray(jax.random.uniform(kv, (count,), dtype))
+    _, first = np.unique(rows * n + cols, return_index=True)
+    first = first[vals[first].astype(np.float32) != 0.0]
+    return rows[first], cols[first], vals[first]
 
 
 def erdos_renyi_matrix(key, m, n, density: float, dtype=jnp.float32):
@@ -108,28 +120,21 @@ def erdos_renyi_matrix(key, m, n, density: float, dtype=jnp.float32):
     actual sparse workload — use :func:`erdos_renyi_bcoo`, which draws from
     the same sampler and feeds ``NMFSolver(backend="sparse")`` directly.
     """
-    mask, vals = _erdos_renyi_sample(key, m, n, density, dtype)
-    return jnp.where(mask, vals, 0.0)
+    rows, cols, vals = _erdos_renyi_sample(key, m, n, density, dtype)
+    return jnp.zeros((m, n), dtype).at[rows, cols].set(vals)
 
 
 def erdos_renyi_bcoo(key, m, n, density: float, dtype=jnp.float32):
     """True sparse storage variant of :func:`erdos_renyi_matrix`: the same
-    entries for the same key, as a ``jax.experimental.sparse.BCOO``.  The
-    triplets are extracted host-side from the shared sampler's (m, n) mask
-    and values (so the sampler itself still allocates two dense arrays —
-    this skips only the masked combine and the fromdense scatter).  Use
-    with ``NMFSolver(backend="sparse")`` (serial/gspmd 1×1 BlockCOO, or
+    entries for the same key, as a ``jax.experimental.sparse.BCOO``, with
+    no dense intermediate anywhere.  Use with
+    ``NMFSolver(backend="sparse")`` (serial/gspmd 1×1 BlockCOO, or
     grid-blockified for faun/naive)."""
     import numpy as np
     from jax.experimental import sparse as jsparse
-    mask, vals = _erdos_renyi_sample(key, m, n, density, dtype)
-    # Drop masked entries whose value rounds to exactly 0 in `dtype` (bf16
-    # can) so the result is identical to BCOO.fromdense of the dense form.
-    nz = np.asarray(mask) & (np.asarray(vals, np.float32) != 0.0)
-    rows, cols = np.nonzero(nz)
-    data = jnp.asarray(np.asarray(vals)[rows, cols])
+    rows, cols, vals = _erdos_renyi_sample(key, m, n, density, dtype)
     indices = jnp.asarray(np.stack([rows, cols], axis=1), dtype=jnp.int32)
-    return jsparse.BCOO((data, indices), shape=(m, n))
+    return jsparse.BCOO((jnp.asarray(vals), indices), shape=(m, n))
 
 
 def stream_truth(seed: int, n: int, k: int, dtype=jnp.float32):
@@ -170,14 +175,39 @@ def stream_batch(seed: int, step: int, *, rows: int, n: int, k: int,
 
 
 def video_like_matrix(key, m, n, *, rank: int = 20, motion: float = 0.05,
-                      dtype=jnp.float32):
+                      dtype=jnp.float32, row0: int = 0, sharding=None):
     """Static low-rank background + sparse 'moving object' outliers
-    (the paper's video use-case structure)."""
-    A = lowrank_matrix(key, m, n, rank, dtype=dtype)
-    k1, k2 = jax.random.split(jax.random.fold_in(key, 1))
-    mask = jax.random.bernoulli(k1, motion, (m, n))
-    obj = jax.random.uniform(k2, (m, n), dtype)
-    return jnp.where(mask, A + obj, A)
+    (the paper's video use-case structure): rows ``row0 .. row0+m-1`` of
+    one unbounded matrix.
+
+    Row i is a pure function of (key, i) — its background mix, mask and
+    object values come from ``fold_in(key, i)`` — so any row range, e.g.
+    new frames to fold in after training on the first m, is drawn without
+    the rows before it.  The whole draw is one fused elementwise pass
+    behind the background product: A is the only (m, n) buffer the
+    compiled program allocates.  ``sharding`` places the result (a
+    ``NamedSharding`` generates each device's block on that device)."""
+    gen = _video_rows(m, n, rank, float(motion), jnp.dtype(dtype), sharding)
+    return gen(key, jnp.asarray(row0, jnp.int32))
+
+
+@functools.lru_cache(maxsize=None)
+def _video_rows(m, n, rank, motion, dtype, sharding):
+    def gen(key, row0):
+        kh, kw, km, ko = jax.random.split(key, 4)
+        H = jax.random.uniform(kh, (rank, n), dtype)
+        rows = row0 + jnp.arange(m, dtype=jnp.int32)
+
+        def per_row(k, draw):
+            return jax.vmap(lambda i: draw(jax.random.fold_in(k, i)))(rows)
+
+        W = per_row(kw, lambda k: jax.random.uniform(k, (rank,), dtype))
+        mask = per_row(km, lambda k: jax.random.bernoulli(k, motion, (n,)))
+        obj = per_row(ko, lambda k: jax.random.uniform(k, (n,), dtype))
+        A = dot(W, H).astype(dtype)
+        return jnp.where(mask, A + obj, A)
+
+    return jax.jit(gen, out_shardings=sharding)
 
 
 def bow_like_matrix(key, vocab, docs, *, topics: int = 20,

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.backends.base import ATB, dot
+
 
 def _gram_kernel(x_ref, o_ref):
     i = pl.program_id(0)
@@ -27,9 +29,7 @@ def _gram_kernel(x_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     x = x_ref[...]
-    o_ref[...] += jax.lax.dot_general(
-        x, x, dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    o_ref[...] += dot(x, x, ATB)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "interpret"))

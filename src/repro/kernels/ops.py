@@ -1,12 +1,17 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Responsibilities:
-  * shape legalisation — pad rows to the block multiple and k to the MXU
-    lane width (128) with zeros (all kernels are zero-padding-safe by
-    construction; see each module's docstring), then slice back;
-  * backend dispatch — compiled Pallas on TPU, interpret=True elsewhere
-    (the container is CPU-only; interpret mode executes the same kernel
-    body in Python for correctness validation);
+  * shape legalisation — pad the k-wide operands (factor panels, COO
+    triplets) to the MXU lane width (128) and to whole tiles with zeros
+    (all kernels are zero-padding-safe by construction; see each module's
+    docstring), then slice back.  The data matrix A is never padded: the
+    tall-skinny products run a ``cdiv`` grid whose ragged edge tiles mask
+    what they read past A, since a padded copy would double A's footprint;
+  * backend dispatch — compiled Pallas (Mosaic) when the default backend
+    is TPU, ``interpret=True`` on any other backend, where the same kernel
+    body runs in Python for correctness tests.  The TPU path is guarded
+    by ``tests/test_chip_compile.py``, which compiles each kernel for a
+    described v5e at the widths ``chip_smoke.py`` runs;
   * block-size selection — hand heuristics sized for ~16 MB VMEM working
     sets by default, or the *measured* choice from kernels/autotune.py when
     ``autotune=True`` (the search always includes the heuristic, so tuning
@@ -62,12 +67,6 @@ def _block(size: int, target: int) -> int:
     return b
 
 
-def _block8(size: int, target: int) -> int:
-    """Largest divisor of `size` that is a multiple of 8 and <= target
-    (`size` must itself be a multiple of 8)."""
-    return 8 * _block(size // 8, max(target // 8, 1))
-
-
 def _candidates(size: int, default: int, interp_targets, tpu_targets,
                 interpret: bool, *, pick=_block) -> list[int]:
     """Divisor-legal candidate block sizes for one dimension, always
@@ -108,10 +107,11 @@ def _isynth(shape, n: int, *, seed: int = 0) -> jax.Array:
 
 def gram(X: jax.Array, *, block_m: int | None = None,
          autotune: bool = False) -> jax.Array:
-    """XᵀX (fp32) for arbitrary (m, k)."""
+    """XᵀX (fp32) for arbitrary (m, k).  X is a k-wide factor panel, so
+    padding its rows to a whole number of row panels is cheap."""
     interpret = not _on_tpu()
     m, k = X.shape
-    Xp = _pad_to(_pad_to(X, 1, LANE), 0, 8)
+    Xp = _pad_to(_pad_to(X, 1, LANE), 0, 8 if interpret else 512)
     default = _block(Xp.shape[0], _MAX_INTERP_BLOCK if interpret else 512)
     bm = block_m or default
     if block_m is None and autotune:
@@ -131,65 +131,79 @@ def gram(X: jax.Array, *, block_m: int | None = None,
     return out[:k, :k]
 
 
-def _tune_ts(fn, name, Ap, Bp, interpret, default_m, default_n):
-    key = (Ap.shape, Bp.shape, Ap.dtype)
+def _tile(size: int, target: int, mult: int) -> int:
+    """Tile length for a ``cdiv`` grid over ``size``: the whole dimension
+    when it fits ``target`` (always a legal block), else ``target`` rounded
+    down to the layout multiple ``mult`` (8 sublanes / 128 lanes)."""
+    t = max(mult, target - target % mult)
+    return size if size <= t else t
+
+
+def _tile_ok(size: int, mult: int):
+    return lambda b: b == size or (b % mult == 0 and b < size)
+
+
+def _ts_call(fn, A, B, bm: int, bn: int, interpret: bool, contract: int):
+    """Run a ts_matmul kernel on unpadded A: B (k-wide, cheap to copy) is
+    padded to the MXU lane width and to a whole number of contraction
+    tiles, with zeros."""
+    tile = bn if contract == 1 else bm
+    Bp = _pad_to(_pad_to(B, 1, LANE), 0, tile)
+    return fn(A, Bp, block_m=bm, block_n=bn, interpret=interpret)
+
+
+def _ts_blocks(name, fn, A, B, contract, interpret, default_m, default_n,
+               autotune):
+    m, n = A.shape
+    bm = _tile(m, _MAX_INTERP_BLOCK if interpret else default_m, 8)
+    bn = _tile(n, _MAX_INTERP_BLOCK if interpret else default_n, LANE)
+    if not autotune:
+        return bm, bn
+    key = (A.shape, B.shape, A.dtype)
     # hot path: validated cache hit needs no synthetic inputs
-    cached = _cached_params(name, key, lambda b: Ap.shape[0] % b == 0,
-                            lambda b: Ap.shape[1] % b == 0)
+    cached = _cached_params(name, key, _tile_ok(m, 8), _tile_ok(n, LANE))
     if cached is not None:
         return cached
-    cands_m = _candidates(Ap.shape[0], default_m, (16, 32, 64),
-                          (128, 256, 512), interpret)
-    cands_n = _candidates(Ap.shape[1], default_n, (16, 32, 64),
-                          (128, 256, 512), interpret)
-    syn = functools.cache(lambda: (_synth(Ap.shape, Ap.dtype),
-                                   _synth(Bp.shape, Bp.dtype, seed=1)))
+    cands_m = sorted({_tile(m, t, 8) for t in
+                      ((16, 32, 64) if interpret else (128, 256, 512))}
+                     | {bm})
+    cands_n = sorted({_tile(n, t, LANE) for t in
+                      ((128,) if interpret else (128, 256, 512, 1024))}
+                     | {bn})
+    syn = functools.cache(lambda: (_synth(A.shape, A.dtype),
+                                   _synth(B.shape, B.dtype, seed=1)))
     return _at.tune(name, key,
                     [(cm, cn) for cm in cands_m for cn in cands_n],
-                    lambda p: fn(*syn(), block_m=p[0], block_n=p[1],
-                                 interpret=interpret))
+                    lambda p: _ts_call(fn, *syn(), p[0], p[1], interpret,
+                                       contract))
 
 
 def ts_matmul(A: jax.Array, B: jax.Array, *, block_m: int | None = None,
               block_n: int | None = None,
               autotune: bool = False) -> jax.Array:
-    """A @ B (fp32) for arbitrary (m, n) × (n, k)."""
+    """A @ B (fp32) for arbitrary (m, n) × (n, k); A is never copied."""
     interpret = not _on_tpu()
-    m, n = A.shape
     k = B.shape[1]
-    Ap = _pad_to(_pad_to(A, 0, 8), 1, LANE)
-    Bp = _pad_to(B, 1, LANE)
-    if Bp.shape[0] != Ap.shape[1]:   # match B's rows to A's padded cols
-        Bp = jnp.pad(Bp, ((0, Ap.shape[1] - Bp.shape[0]), (0, 0)))
-    cap = _MAX_INTERP_BLOCK if interpret else None
-    bm = block_m or _block(Ap.shape[0], cap or 256)
-    bn = block_n or _block(Ap.shape[1], cap or 512)
-    if block_m is None and block_n is None and autotune:
-        bm, bn = _tune_ts(_ts.ts_matmul, "ts_matmul", Ap, Bp, interpret,
-                          bm, bn)
-    out = _ts.ts_matmul(Ap, Bp, block_m=bm, block_n=bn, interpret=interpret)
-    return out[:m, :k]
+    bm, bn = _ts_blocks("ts_matmul", _ts.ts_matmul, A, B, 1, interpret,
+                        256, 512,
+                        autotune and block_m is None and block_n is None)
+    out = _ts_call(_ts.ts_matmul, A, B, block_m or bm, block_n or bn,
+                   interpret, contract=1)
+    return out[:, :k]
 
 
 def ts_matmul_t(A: jax.Array, B: jax.Array, *, block_m: int | None = None,
                 block_n: int | None = None,
                 autotune: bool = False) -> jax.Array:
-    """Aᵀ @ B (fp32) for arbitrary (m, n) × (m, k)."""
+    """Aᵀ @ B (fp32) for arbitrary (m, n) × (m, k); A is never copied."""
     interpret = not _on_tpu()
-    n = A.shape[1]
     k = B.shape[1]
-    Ap = _pad_to(_pad_to(A, 0, LANE), 1, 8)
-    Bp = _pad_to(_pad_to(B, 1, LANE), 0, LANE)
-    if Bp.shape[0] != Ap.shape[0]:
-        Bp = jnp.pad(Bp, ((0, Ap.shape[0] - Bp.shape[0]), (0, 0)))
-    cap = _MAX_INTERP_BLOCK if interpret else None
-    bm = block_m or _block(Ap.shape[0], cap or 512)
-    bn = block_n or _block(Ap.shape[1], cap or 256)
-    if block_m is None and block_n is None and autotune:
-        bm, bn = _tune_ts(_ts.ts_matmul_t, "ts_matmul_t", Ap, Bp, interpret,
-                          bm, bn)
-    out = _ts.ts_matmul_t(Ap, Bp, block_m=bm, block_n=bn, interpret=interpret)
-    return out[:n, :k]
+    bm, bn = _ts_blocks("ts_matmul_t", _ts.ts_matmul_t, A, B, 0, interpret,
+                        512, 256,
+                        autotune and block_m is None and block_n is None)
+    out = _ts_call(_ts.ts_matmul_t, A, B, block_m or bm, block_n or bn,
+                   interpret, contract=0)
+    return out[:, :k]
 
 
 def spmm(vals: jax.Array, rows: jax.Array, cols: jax.Array, B: jax.Array,
@@ -201,7 +215,7 @@ def spmm(vals: jax.Array, rows: jax.Array, cols: jax.Array, B: jax.Array,
     n, k = B.shape
     Bp = _pad_to(_pad_to(B, 1, LANE), 0, 8)
     m_pad = m_out + (-m_out) % 8
-    default = _MAX_INTERP_BLOCK if interpret else 512
+    default = _MAX_INTERP_BLOCK if interpret else _spmm.SMEM_TILE
     bnz = block_nnz or default
     if block_nnz is None and autotune and vals.shape[0]:
         key = (vals.shape[0], m_pad, Bp.shape, vals.dtype)
@@ -210,8 +224,9 @@ def spmm(vals: jax.Array, rows: jax.Array, cols: jax.Array, B: jax.Array,
         if cached is not None:
             (bnz,) = cached
         else:
+            tile = _spmm.SMEM_TILE
             cands = _candidates(vals.shape[0], default, (16, 32, 64),
-                                (256, 512, 1024), interpret,
+                                (tile, 2 * tile, 4 * tile), interpret,
                                 pick=lambda s, t: min(s + (-s) % 8, t))
             syn = functools.cache(
                 lambda: (_synth(vals.shape, vals.dtype),
@@ -236,14 +251,15 @@ def spmm_t(vals: jax.Array, rows: jax.Array, cols: jax.Array, B: jax.Array,
                 autotune=autotune)
 
 
-def _synth_sorted(L, align, m_pad, Bp, dtype):
+def _synth_sorted(L, align, unit_rows, m_pad, Bp, dtype):
     """Consistent synthetic sort_rows layout for tuning runs: U full units
-    with non-decreasing tile ids and rows inside each unit's tile."""
+    with non-decreasing tile ids and rows inside each unit's rows."""
     U = L // align
     rng = np.random.RandomState(0)
-    tiles = np.sort(rng.randint(0, m_pad // 8, size=U)).astype(np.int32)
-    rows = (np.repeat(tiles, align) * 8
-            + rng.randint(0, 8, size=L)).astype(np.int32)
+    units = np.sort(rng.randint(0, m_pad // unit_rows, size=U))
+    tiles = (units * (unit_rows // 8)).astype(np.int32)
+    rows = (np.repeat(units, align) * unit_rows
+            + rng.randint(0, unit_rows, size=L)).astype(np.int32)
     cols = rng.randint(0, Bp.shape[0], size=L).astype(np.int32)
     valid = np.full(U, align, np.int32)
     return (_synth((L,), dtype), jnp.asarray(rows), jnp.asarray(cols),
@@ -253,7 +269,7 @@ def _synth_sorted(L, align, m_pad, Bp, dtype):
 
 def spmm_sorted(vals: jax.Array, rows: jax.Array, cols: jax.Array,
                 offsets: jax.Array, tiles: jax.Array, valid: jax.Array,
-                B: jax.Array, m_out: int, *, align: int,
+                B: jax.Array, m_out: int, *, align: int, unit_rows: int = 8,
                 block_m: int | None = None, block_nnz: int | None = None,
                 autotune: bool = False) -> jax.Array:
     """A_blk @ B (fp32) from the row-sorted ``sort_rows`` packed layout —
@@ -261,34 +277,48 @@ def spmm_sorted(vals: jax.Array, rows: jax.Array, cols: jax.Array,
 
     ``offsets`` is the (m_out+1,) per-row segment-offset array; rows that
     own no triplets may sit in output tiles the kernel never visits, so
-    they are masked to exact zeros here.
+    they are masked to exact zeros here.  ``unit_rows`` is the layout's
+    row unit: the accumulator tile (``block_m``) is a multiple of it.
     """
     interpret = not _on_tpu()
     n, k = B.shape
     Bp = _pad_to(_pad_to(B, 1, LANE), 0, 8)
-    m_pad = m_out + (-m_out) % 8
-    default_m = 8 if interpret else _block8(m_pad, 64)
-    default_nnz = _block(align, _MAX_INTERP_BLOCK if interpret else 512)
+    m_pad = m_out + (-m_out) % unit_rows
+    units = lambda s, t: unit_rows * _block(s // unit_rows,
+                                            max(t // unit_rows, 1))
+    default_m = unit_rows if interpret else units(m_pad, 64)
+    if interpret:
+        default_nnz = _block(align, _MAX_INTERP_BLOCK)
+    elif align % _spmm.SMEM_TILE:
+        raise ValueError(f"the compiled sorted SpMM streams triplets in "
+                         f"chunks of a multiple of {_spmm.SMEM_TILE}; sort "
+                         f"with an align that is one (got align={align})")
+    else:
+        default_nnz = _spmm.SMEM_TILE
     bm = block_m or default_m
     bnz = block_nnz or default_nnz
     if block_m is None and block_nnz is None and autotune and vals.shape[0]:
-        key = (vals.shape[0], align, m_pad, Bp.shape, vals.dtype)
+        key = (vals.shape[0], align, unit_rows, m_pad, Bp.shape, vals.dtype)
         # hot path: validated cache hit needs no synthetic inputs
         cached = _cached_params(
             "spmm_sorted", key,
-            lambda b: b % 8 == 0 and m_pad % b == 0,
-            lambda b: align % b == 0)
+            lambda b: b % unit_rows == 0 and m_pad % b == 0,
+            lambda b: align % b == 0
+            and (interpret or b % _spmm.SMEM_TILE == 0))
         if cached is not None:
             bm, bnz = cached
         else:
             cands_m = _candidates(m_pad, default_m, (8, 16, 32),
                                   (64, 128, 256, 512), interpret,
-                                  pick=_block8)
-            cands_z = _candidates(align, default_nnz, (16, 32, 64),
-                                  (128, 256, 512), interpret)
+                                  pick=units)
+            tile = _spmm.SMEM_TILE
+            cands_z = _candidates(
+                align, default_nnz, (16, 32, 64), (tile, 2 * tile),
+                interpret, pick=_block if interpret else
+                lambda s, t: tile * _block(s // tile, t // tile))
             syn = functools.cache(
-                lambda: _synth_sorted(vals.shape[0], align, m_pad,
-                                      Bp, vals.dtype))
+                lambda: _synth_sorted(vals.shape[0], align, unit_rows,
+                                      m_pad, Bp, vals.dtype))
             bm, bnz = _at.tune(
                 "spmm_sorted", key,
                 [(cm, cz) for cm in cands_m for cz in cands_z],

@@ -11,8 +11,10 @@ reachable through ``repro.backends.SparseOps(spmm_impl=...)``:
     fp32 accumulator (m_blk × k, k padded to the 128 lane width by ops.py)
     stay VMEM-resident for the whole pass; each nonzero issues one
     dynamic-slice row read of B and one scatter-add row update of the
-    output.  No preprocessing needed, but the whole output block is pinned
-    in VMEM, which caps m_blk × k.
+    output.  No preprocessing needed, but B and the whole output block
+    are pinned in VMEM: the kernel raises the compiler's scoped-VMEM limit
+    to what they need, up to ``VMEM_LIMIT`` (n_blk + m_blk ≈ 190k rows at
+    k = 128), and refuses taller blocks.
 
 ``spmm_sorted`` — row-sorted + scalar prefetch (impl="sorted").
     Requires the ``BlockCOO.sort_rows()`` layout (core/blocksparse.py):
@@ -54,6 +56,32 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+#: XLA tiles a 1-D 32-bit array on TPU by 1024 elements, so a triplet
+#: chunk streamed into SMEM as a 1-D block is a whole number of such tiles.
+SMEM_TILE = 1024
+
+#: Scoped-VMEM ceiling for the resident operands (v5e has 128 MiB of VMEM;
+#: the compiler's default scope is 16 MiB).  Beyond it a block is too tall
+#: for these kernels.
+VMEM_LIMIT = 100 * 1024 * 1024
+
+
+def _resident(shape):
+    """BlockSpec of an operand that stays in VMEM for the whole grid: its
+    block never moves, so a single buffer suffices."""
+    index = lambda j, *_: (0,) * len(shape)
+    return pl.BlockSpec(shape, index, pipeline_mode=pl.Buffered(1))
+
+
+def _vmem_params(resident_bytes: int) -> pltpu.CompilerParams:
+    need = resident_bytes + 4 * 1024 * 1024     # + tiles and scratch
+    if need > VMEM_LIMIT:
+        raise ValueError(
+            f"SpMM block needs {need / 2**20:.0f} MiB of VMEM (> "
+            f"{VMEM_LIMIT / 2**20:.0f} MiB); use a finer processor grid")
+    return pltpu.CompilerParams(vmem_limit_bytes=max(need, 16 * 2**20))
+
+
 def _spmm_kernel(vals_ref, rows_ref, cols_ref, b_ref, o_ref, *,
                  block_nnz: int):
     j = pl.program_id(0)
@@ -63,9 +91,9 @@ def _spmm_kernel(vals_ref, rows_ref, cols_ref, b_ref, o_ref, *,
         o_ref[...] = jnp.zeros_like(o_ref)
 
     def body(t, carry):
-        v = vals_ref[0, t].astype(jnp.float32)
-        r = rows_ref[0, t]
-        c = cols_ref[0, t]
+        v = vals_ref[t].astype(jnp.float32)
+        r = rows_ref[t]
+        c = cols_ref[t]
         b = b_ref[pl.ds(c, 1), :].astype(jnp.float32)
         o_ref[pl.ds(r, 1), :] += v * b
         return carry
@@ -94,18 +122,17 @@ def spmm(vals: jax.Array, rows: jax.Array, cols: jax.Array, B: jax.Array, *,
         rows = jnp.pad(rows, (0, pad))
         cols = jnp.pad(cols, (0, pad))
     chunks = (nnz + pad) // block_nnz
-    smem = functools.partial(pl.BlockSpec, (1, block_nnz), lambda j: (j, 0),
+    smem = functools.partial(pl.BlockSpec, (block_nnz,), lambda j: (j,),
                              memory_space=pltpu.SMEM)
     return pl.pallas_call(
         functools.partial(_spmm_kernel, block_nnz=block_nnz),
         grid=(chunks,),
-        in_specs=[smem(), smem(), smem(),
-                  pl.BlockSpec((n, k), lambda j: (0, 0))],
-        out_specs=pl.BlockSpec((m_out, k), lambda j: (0, 0)),
+        in_specs=[smem(), smem(), smem(), _resident((n, k))],
+        out_specs=_resident((m_out, k)),
         out_shape=jax.ShapeDtypeStruct((m_out, k), jnp.float32),
+        compiler_params=_vmem_params((n + m_out) * k * 4),
         interpret=interpret,
-    )(vals.reshape(chunks, block_nnz), rows.reshape(chunks, block_nnz),
-      cols.reshape(chunks, block_nnz), B)
+    )(vals, rows, cols, B)
 
 
 def _spmm_sorted_kernel(ids_ref, lens_ref, vals_ref, rows_ref, cols_ref,
@@ -125,9 +152,9 @@ def _spmm_sorted_kernel(ids_ref, lens_ref, vals_ref, rows_ref, cols_ref,
     base = t * block_m
 
     def body(s, carry):
-        v = vals_ref[0, s].astype(jnp.float32)
-        r = rows_ref[0, s] - base
-        c = cols_ref[0, s]
+        v = vals_ref[s].astype(jnp.float32)
+        r = rows_ref[s] - base
+        c = cols_ref[s]
         o_ref[pl.ds(r, 1), :] += v * b_ref[pl.ds(c, 1), :].astype(jnp.float32)
         return carry
 
@@ -144,10 +171,11 @@ def spmm_sorted(vals: jax.Array, rows: jax.Array, cols: jax.Array,
     ``sort_rows`` layout.
 
     ``vals``/``rows``/``cols`` are the tile-aligned packed triplets (length
-    U·align); ``tiles``/``valid`` the per-align-unit 8-row tile ids and
-    valid counts.  Shape contract (ops.py legalises): m_out a multiple of
-    block_m, block_m a multiple of 8 dividing m_out, block_nnz dividing
-    align, B's rows ≥ max col + 1 and k a multiple of 128 on TPU.
+    U·align); ``tiles``/``valid`` the per-align-unit first 8-row tile ids
+    and valid counts.  Shape contract (ops.py legalises): m_out a multiple
+    of block_m, block_m a multiple of the layout's row unit (8 or more)
+    dividing m_out, block_nnz dividing align, B's rows ≥ max col + 1 and k
+    a multiple of 128 on TPU.
 
     Rows that own no nonzeros may land in output tiles the grid never
     visits; the ops.py wrapper masks them to zero from the row offsets.
@@ -175,14 +203,13 @@ def spmm_sorted(vals: jax.Array, rows: jax.Array, cols: jax.Array,
     lens = jnp.clip(jnp.repeat(valid.astype(jnp.int32), rep)
                     - jnp.tile(jnp.arange(rep, dtype=jnp.int32) * block_nnz,
                                U), 0, block_nnz)
-    smem = functools.partial(pl.BlockSpec, (1, block_nnz),
-                             lambda j, ids, lens: (j, 0),
+    smem = functools.partial(pl.BlockSpec, (block_nnz,),
+                             lambda j, ids, lens: (j,),
                              memory_space=pltpu.SMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(chunks,),
-        in_specs=[smem(), smem(), smem(),
-                  pl.BlockSpec((n, k), lambda j, ids, lens: (0, 0))],
+        in_specs=[smem(), smem(), smem(), _resident((n, k))],
         out_specs=pl.BlockSpec((block_m, k),
                                lambda j, ids, lens: (ids[j], 0)),
     )
@@ -190,7 +217,6 @@ def spmm_sorted(vals: jax.Array, rows: jax.Array, cols: jax.Array,
         functools.partial(_spmm_sorted_kernel, block_m=block_m),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_out, k), jnp.float32),
+        compiler_params=_vmem_params((n + 2 * block_m) * k * 4),
         interpret=interpret,
-    )(ids, lens, vals.reshape(chunks, block_nnz),
-      rows.astype(jnp.int32).reshape(chunks, block_nnz),
-      cols.astype(jnp.int32).reshape(chunks, block_nnz), B)
+    )(ids, lens, vals, rows.astype(jnp.int32), cols.astype(jnp.int32), B)

@@ -51,6 +51,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.backends.base import dot
+
 FORMAT = "nmf-factor-artifact"
 VERSION = 1
 
@@ -64,9 +66,7 @@ class ProjectionState(NamedTuple):
 
 def _gram_fp32(H: jax.Array) -> jax.Array:
     """HHᵀ with fp32 accumulation whatever H's dtype (bf16 factors serve)."""
-    return jax.lax.dot_general(
-        H, H, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    return dot(H, H, ((1,), (1,)))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -41,6 +41,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.backends.base import dot
 from repro.serve.artifact import FactorArtifact
 from repro.util.compat import shard_map
 
@@ -74,9 +75,7 @@ def _scan_core(W, Wn, Q, qnorm, offset, *, k: int, metric: str, chunk: int,
     def body(carry, tile):
         vals, idx = carry
         C, cn, start = tile
-        s = jax.lax.dot_general(
-            Q, C, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (b, chunk)
+        s = dot(Q, C, ((1,), (1,)))                         # (b, chunk)
         if metric == "cosine":
             s = s / (jnp.maximum(cn, _EPS)[None, :] * qnorm[:, None])
         lidx = start + jnp.arange(chunk)                   # local row ids
@@ -220,8 +219,10 @@ def _row_norms(W, G, *, use_gram: bool):
     caches it); recomputing this inside the query scan would dominate the
     request path."""
     Wf = W.astype(jnp.float32)
-    base = jnp.sum((Wf @ G) * Wf, axis=1) if use_gram \
-        else jnp.sum(Wf * Wf, axis=1)
+    if use_gram:
+        base = jnp.sum(dot(Wf, G) * Wf, axis=1)
+    else:
+        base = jnp.sum(Wf * Wf, axis=1)
     return jnp.sqrt(jnp.maximum(base, 0.0))
 
 
@@ -261,7 +262,8 @@ def topk_rows(W, queries, *, k: int = 10, gram=None, metric: str = "dot",
     G = (jnp.asarray(gram, jnp.float32) if use_gram
          else jnp.eye(W.shape[1], dtype=jnp.float32))
     Qf = Q.astype(jnp.float32)
-    Qt = Qf @ G if use_gram else Qf            # transform queries once
+    # transform queries once
+    Qt = dot(Qf, G) if use_gram else Qf
     if metric == "cosine":
         if row_norms is None:
             row_norms = _row_norms(W, G, use_gram=use_gram)

@@ -1,30 +1,21 @@
-"""Small compatibility shims across jax versions (0.6 – 0.8+)."""
+"""Mesh and shard_map spellings shared by every schedule (JAX 0.9)."""
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-    """shard_map moved to jax.shard_map and check_rep→check_vma in 0.8."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check)
-        except TypeError:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as sm  # type: ignore
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=check)
+    """``jax.shard_map`` with replication checking off by default: the
+    schedules' per-device panels are deliberately device-varying."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def make_mesh(shape, axis_names, *, devices=None):
-    """jax.make_mesh with the pre-0.9 Auto axis-type behaviour, warning-free."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, axis_names,
-                             axis_types=(AxisType.Auto,) * len(axis_names),
-                             devices=devices)
-    except (ImportError, TypeError):
-        return jax.make_mesh(shape, axis_names, devices=devices)
+    """``jax.make_mesh`` with Auto axis types, so shardings inside jit are
+    left to the partitioner as the schedules expect."""
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)

@@ -30,8 +30,12 @@ from __future__ import annotations
 import os
 import re
 
-__all__ = ["configure", "enable_x64", "force_host_device_count",
-           "gpu_xla_flags", "merge_xla_flags", "set_platform"]
+__all__ = ["compile_cache", "configure", "enable_x64",
+           "force_host_device_count", "gpu_xla_flags", "merge_xla_flags",
+           "set_platform"]
+
+#: Environment variable JAX reads its persistent compilation cache from.
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 #: The GPU flag set from jax's own performance-tips page; a starting point,
 #: not gospel — benchmarks should re-validate on their hardware.
@@ -88,6 +92,22 @@ def set_platform(platform: str = "cpu") -> None:
     if "jax" in _loaded_modules():
         import jax
         jax.config.update("jax_platform_name", platform)
+
+
+def compile_cache(root: str) -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses it and
+    nothing else is set here.  Otherwise the cache goes to ``.jax_cache``
+    under ``root`` (a checkout; the directory is gitignored): a fixed path,
+    because the path is part of the cache key, so a name made from a
+    process id or the time would never hit.  Imports jax."""
+    import jax
+    path = os.environ.get(COMPILE_CACHE_ENV)
+    if not path:
+        path = os.path.join(os.path.abspath(root), ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def enable_x64(on: bool = True) -> None:

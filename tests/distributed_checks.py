@@ -5,7 +5,7 @@ any failure."""
 
 from repro.util import env
 
-env.configure(host_device_count=8)   # before any jax import
+env.configure(host_device_count=8, platform="cpu")   # before any jax import
 
 import sys
 import traceback

@@ -22,7 +22,7 @@ Covers the elastic-runtime acceptance criteria on real device meshes:
 
 from repro.util import env
 
-env.configure(host_device_count=8)   # before any jax import
+env.configure(host_device_count=8, platform="cpu")   # before any jax import
 
 import os
 import sys
@@ -92,6 +92,13 @@ def _close(res, ref, what, rtol=1e-4, atol=1e-5):
                                rtol=rtol, atol=atol, err_msg=what)
     np.testing.assert_allclose(np.asarray(res.H), np.asarray(ref.H),
                                rtol=rtol, atol=atol, err_msg=what)
+
+
+def _direct_rel(res):
+    """‖A − WH‖ / ‖A‖ of a result's factors, in float64 on the host."""
+    W = np.asarray(res.W, np.float64)
+    H = np.asarray(res.H, np.float64)
+    return float(np.linalg.norm(A - W @ H) / np.linalg.norm(A))
 
 
 def _faun(grid_shape, **kw):
@@ -178,9 +185,11 @@ def _():
     # quantization scale (residuals restart at zero and quantization
     # noise differs per grid), not float-roundoff scale — so: loose
     # factor agreement + tight convergence-quality agreement.
+    # Quality is the direct error of the returned factors: the per-step
+    # rel_errors of a compressed run are computed from int8-quantized
+    # byproducts and swing by a few 1e-3 from one step to the next.
     _close(res2, ref, "int8 remesh vs uninterrupted", rtol=5e-2, atol=1e-2)
-    assert abs(float(np.asarray(res2.rel_errors)[-1])
-               - float(np.asarray(ref.rel_errors)[-1])) < 1e-3, \
+    assert abs(_direct_rel(res2) - _direct_rel(ref)) < 1e-3, \
         "int8 remesh must converge to the same quality"
 
 

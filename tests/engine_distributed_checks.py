@@ -16,7 +16,7 @@ Covers the acceptance criteria of the engine refactor:
 
 from repro.util import env
 
-env.configure(host_device_count=8)   # before any jax import
+env.configure(host_device_count=8, platform="cpu")   # before any jax import
 
 import sys
 import traceback
@@ -235,6 +235,21 @@ def _():
     # all-reduces: (m,k)+(n,k) partial products + k×k Grams + error scalars
     ar_bound = 2 * (M + N) * K * 4 + 8 * K * K * 4
     assert st.bytes_moved["all-reduce"] <= ar_bound, st.bytes_moved
+
+
+@check("gspmd_dense_rows_above_row_block_gather_panels_only")
+def _():
+    # Local backends sum long row contractions in ROW_BLOCK blocks; the
+    # global view must not: a dynamic slice along the sharded row dim makes
+    # the partitioner gather what it walks (W's rows, for the Gram).
+    from repro.backends.base import ROW_BLOCK
+    m = 2 * ROW_BLOCK + 40
+    grid = faun.make_faun_mesh(4, 2)
+    for algo in ("mu", "hals"):
+        solver = NMFSolver(K, algo=algo, schedule="gspmd", grid=grid)
+        st = collective_stats(solver.lower_step(m, N).compile().as_text())
+        assert st.bytes_moved["all-gather"] <= (m + N) * K * 4, (
+            algo, st.bytes_moved)
 
 
 @check("sparse_multipod_grid")

@@ -19,7 +19,7 @@ Covers the acceptance criteria of the online train→serve loop on a mesh:
 
 from repro.util import env
 
-env.configure(host_device_count=8)   # before any jax import
+env.configure(host_device_count=8, platform="cpu")   # before any jax import
 
 import os
 import sys

@@ -21,7 +21,7 @@ Covers the acceptance criteria of mesh-sharded serving:
 
 from repro.util import env
 
-env.configure(host_device_count=8)   # before any jax import
+env.configure(host_device_count=8, platform="cpu")   # before any jax import
 
 import os
 import sys

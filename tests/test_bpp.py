@@ -82,3 +82,106 @@ def test_bpp_objective_not_worse_than_projection(seed):
     naive = jnp.maximum(jnp.linalg.lstsq(C, b)[0].T, 0.0)
     f = lambda x: float(jnp.sum((C @ x.T - b) ** 2))
     assert f(X) <= f(naive) + 1e-4 * f(naive)
+
+
+def test_rank_deficient_gram_gives_finite_solution():
+    """k above the data's rank makes passive blocks of G singular; the
+    solve must stay finite and still fit an exactly representable B."""
+    key = jax.random.PRNGKey(5)
+    C = (jax.random.uniform(key, (30, 3))
+         @ jax.random.uniform(jax.random.fold_in(key, 1), (3, 8)))
+    X_true = jax.random.uniform(jax.random.fold_in(key, 2), (6, 8))
+    B = C @ X_true.T
+    G, R = C.T @ C, (C.T @ B).T
+    X = solve_bpp(G, R)
+    assert np.isfinite(np.asarray(X)).all()
+    assert float(jnp.min(X)) >= 0.0
+    resid = jnp.linalg.norm(C @ X.T - B) / jnp.linalg.norm(B)
+    assert float(resid) < 1e-3
+
+
+def test_row_chunks_match_one_batch(monkeypatch):
+    """Right-hand sides are pivoted in row chunks; each row's solution is
+    independent of which chunk it lands in (incl. the zero-padded tail)."""
+    from repro.core import bpp
+    key = jax.random.PRNGKey(9)
+    C = jax.random.normal(key, (60, 7))
+    B = jax.random.normal(jax.random.fold_in(key, 1), (60, 37))
+    G, R = C.T @ C, (C.T @ B).T
+    one = bpp.solve_bpp(G, R)
+    monkeypatch.setattr(bpp, "ROW_CHUNK", 16)
+    chunked = bpp.solve_bpp(G, R)
+    np.testing.assert_allclose(np.asarray(chunked), np.asarray(one),
+                               rtol=0, atol=1e-6)
+
+
+def test_column_scaling_leaves_the_fit_unchanged():
+    """NNLS is blind to the scale of C's columns (x_j absorbs it), so the
+    fit must be too: with factor columns 1000× apart, a regulariser sized
+    by the largest column swamped the small ones (fits 12% worse)."""
+    rng = np.random.RandomState(0)
+    C0 = rng.rand(400, 50)
+    B = C0 @ (rng.rand(50, 64) * (rng.rand(50, 64) > 0.5)) \
+        + 0.01 * rng.rand(400, 64)
+    C = C0 * 10.0 ** rng.uniform(-3, 0, 50)[None, :]
+
+    def fit(C):
+        G = jnp.asarray(C.T @ C, jnp.float32)
+        R = jnp.asarray((C.T @ B).T, jnp.float32)
+        X = np.asarray(solve_bpp(G, R), np.float64)
+        return np.sum((C @ X.T - B) ** 2, axis=0)
+
+    f0, f = fit(C0), fit(C)
+    assert np.all(f <= f0 * (1 + 1e-4) + 1e-9)
+
+
+def test_ill_conditioned_gram_rows_reach_kkt():
+    """Collinear factor columns (k = 50 over rank-20 data, Gram condition
+    ~1e8) leave fp32 passive solves numerically singular: rows whose
+    pivoting cycles are re-solved with a small Tikhonov term, so every row
+    ends at (or within 1e-3 of) its KKT point, none clamped mid-pivot."""
+    rng = np.random.RandomState(1)
+    C = rng.rand(2000, 20) @ rng.rand(20, 50) + 0.01 * rng.rand(2000, 50)
+    B = rng.rand(2000, 20) @ rng.rand(20, 300)
+    G, R = C.T @ C, (C.T @ B).T
+    X = np.asarray(solve_bpp(jnp.asarray(G, jnp.float32),
+                             jnp.asarray(R, jnp.float32)), np.float64)
+    Y = X @ G - R
+    scale = np.abs(R).max(axis=1, keepdims=True)
+    viol = np.where(X > 0, np.abs(Y), np.maximum(-Y, 0)) / scale
+    assert X.min() >= 0 and viol.max() < 1e-3
+
+
+def _collinear_problem():
+    rng = np.random.RandomState(1)
+    C = rng.rand(2000, 20) @ rng.rand(20, 50) + 0.01 * rng.rand(2000, 50)
+    B = rng.rand(2000, 20) @ rng.rand(20, 300)
+    return (jnp.asarray(C.T @ C, jnp.float32),
+            jnp.asarray((C.T @ B).T, jnp.float32))
+
+
+def test_counts_regularised_and_clamped_rows():
+    """``counts=True`` reports the rows re-solved with a Tikhonov term and
+    the rows clamped: none on a well-conditioned Gram; on a collinear one
+    the cycling rows are counted as re-solved, and none is clamped."""
+    key = jax.random.PRNGKey(3)
+    C = jax.random.uniform(key, (80, 6))
+    B = jax.random.uniform(jax.random.fold_in(key, 1), (80, 40))
+    X, c = solve_bpp(C.T @ C, (C.T @ B).T, counts=True)
+    assert {k: int(v) for k, v in c.items()} == {"regularised_rows": 0,
+                                                 "clamped_rows": 0}
+    np.testing.assert_array_equal(np.asarray(X),
+                                  np.asarray(solve_bpp(C.T @ C, (C.T @ B).T)))
+    _, c = solve_bpp(*_collinear_problem(), counts=True)
+    assert int(c["regularised_rows"]) > 0 and int(c["clamped_rows"]) == 0
+
+
+def test_fit_reports_bpp_row_counts():
+    """A BPP fit carries the counts through its compiled loop into
+    ``extras["rule_state"]``, summed over the half-steps."""
+    from repro.core.engine import NMFSolver
+    A = jax.random.uniform(jax.random.PRNGKey(4), (40, 30))
+    res = NMFSolver(4, algo="bpp", max_iters=3).fit(A)
+    st = res.extras["rule_state"]
+    assert set(st) == {"regularised_rows", "clamped_rows"}
+    assert 0 <= int(st["clamped_rows"]) <= int(st["regularised_rows"])

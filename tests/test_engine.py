@@ -6,7 +6,6 @@ subprocess (engine_distributed_checks.py) and are marked ``slow``, so
 ``pytest -m "not slow"`` finishes in minutes.
 """
 
-import functools
 import os
 import subprocess
 import sys
@@ -33,7 +32,11 @@ HERE = os.path.dirname(__file__)
 @pytest.mark.parametrize("algo", ["mu", "hals", "bpp"])
 def test_serial_engine_bitmatches_reference_loop(algo):
     """NMFSolver(schedule="serial") must reproduce a hand-rolled python loop
-    over aunmf_step bit-for-bit (the old aunmf.fit behaviour)."""
+    over aunmf_step bit-for-bit (the old aunmf.fit behaviour).
+
+    The loop carries H transposed, (n, k), as the engine does: XLA picks
+    the dot's summation order from its operands' layouts, so a loop that
+    carries (k, n) differs from the engine in the last bit."""
     from repro.core import algorithms
     from repro.core.error import sq_frobenius
 
@@ -43,15 +46,19 @@ def test_serial_engine_bitmatches_reference_loop(algo):
 
     update_w, update_h = algorithms.get_update_fns(algo)
     normA_sq = sq_frobenius(A)
-    step = jax.jit(functools.partial(aunmf.aunmf_step, update_w=update_w,
-                                     update_h=update_h, normA_sq=normA_sq))
-    W, H = W0, jnp.asarray(H0)
+
+    @jax.jit
+    def step(A, W, Ht):
+        W, H, _ = aunmf.aunmf_step(A, W, Ht.T, update_w, update_h, normA_sq)
+        return W, H.T
+
+    W, Ht = W0, jnp.asarray(H0).T
     for _ in range(iters):
-        W, H, _ = step(A, W, H)
+        W, Ht = step(A, W, Ht)
 
     res = NMFSolver(k, algo=algo, max_iters=iters).fit(A, key=KEY)
     np.testing.assert_array_equal(np.asarray(res.W), np.asarray(W))
-    np.testing.assert_array_equal(np.asarray(res.H), np.asarray(H))
+    np.testing.assert_array_equal(np.asarray(res.H), np.asarray(Ht.T))
 
 
 def test_legacy_fit_is_engine_wrapper():
@@ -246,6 +253,34 @@ def test_low_precision_input_fit(backend):
                                                                     key=KEY)
     assert res.W.dtype == jnp.bfloat16
     assert np.isfinite(np.asarray(res.rel_errors, np.float32)).all()
+
+
+# ------------------------------------------------------ row contractions
+
+@pytest.mark.parametrize("m", [16, 50, 64])
+def test_contract_rows_blocks_match_one_dot(m):
+    """Row blocks (the last one shifted back to end at row m, its repeated
+    rows zeroed) sum to the one-dot Aᵀ·B."""
+    from repro.backends.base import contract_rows
+    Am = jax.random.uniform(KEY, (m, 12))
+    B = jax.random.uniform(jax.random.fold_in(KEY, 1), (m, 5))
+    np.testing.assert_allclose(np.asarray(contract_rows(Am, B, 16)),
+                               np.asarray(contract_rows(Am, B, None)),
+                               rtol=1e-6)
+
+
+@pytest.mark.parametrize("backend", ["dense", "sparse"])
+def test_global_view_ops_contract_rows_in_one_dot(backend):
+    """gspmd's variant of a backend contracts rows in one dot (a dynamic
+    slice along a sharded dim would be gathered), and is its own fixed
+    point with a distinct compile-cache key."""
+    from repro.backends import get_backend
+    from repro.backends.base import ROW_BLOCK
+    ops = get_backend(backend)
+    g = ops.global_view_ops()
+    assert ops.row_block == ROW_BLOCK and g.row_block is None
+    assert g.global_view_ops() is g
+    assert g.cache_key() != ops.cache_key()
 
 
 # ------------------------------------------------------ LocalOps registry

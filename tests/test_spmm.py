@@ -43,11 +43,13 @@ def _block(blk: blocksparse.BlockCOO, i: int, j: int) -> blocksparse.BlockCOO:
     inside shard_map) — slicing every leaf, sort metadata included."""
     fields = {f.name: getattr(blk, f.name)[i:i + 1, j:j + 1]
               for f in dataclasses.fields(blk)
-              if f.name not in ("shape", "block_shape", "nnz", "align")
+              if f.name not in ("shape", "block_shape", "nnz", "align",
+                                "unit_rows")
               and getattr(blk, f.name) is not None}
     return blocksparse.BlockCOO(shape=blk.block_shape,
                                 block_shape=blk.block_shape, nnz=blk.nnz,
-                                align=blk.align, **fields)
+                                align=blk.align, unit_rows=blk.unit_rows,
+                                **fields)
 
 
 def _grid_spmm(blk, B, impl):
@@ -160,7 +162,7 @@ def test_sort_rows_layout_invariants():
     """Per-block invariants the sorted kernel relies on: rows
     non-decreasing within each valid segment, offsets consistent with the
     per-row counts, tile ids non-decreasing, valid ≤ align, and packed
-    segments that never cross an 8-row tile boundary."""
+    segments that never cross a row unit's boundary."""
     Ad = erdos_renyi_matrix(jax.random.PRNGKey(5), 64, 40, 0.15)
     srt = blocksparse.blockify(Ad, 2, 2).sort_rows(align=SORT_ALIGN)
     gr, gc = srt.grid
@@ -182,11 +184,50 @@ def test_sort_rows_layout_invariants():
             assert (counts >= 0).all()
             assert (np.diff(tiles) >= 0).all()
             assert ((valid >= 0) & (valid <= SORT_ALIGN)).all()
+            unit = srt.unit_rows
             for u, t in enumerate(tiles):
                 seg = rows[u * SORT_ALIGN:u * SORT_ALIGN + valid[u]]
                 assert (np.diff(seg) >= 0).all()
-                # all valid rows of a unit live inside the unit's 8-row tile
-                assert ((seg >= t * 8) & (seg < (t + 1) * 8)).all()
+                # all valid rows of a unit live inside the unit's rows
+                assert t * 8 % unit == 0
+                assert ((seg >= t * 8) & (seg < t * 8 + unit)).all()
+
+
+def test_sorted_row_units_span_several_tiles():
+    """A sparse block packs row units of several 8-row tiles (so a large
+    align pads a fraction of the data, not a multiple of it); the products
+    are unchanged and every unit's rows stay inside the unit."""
+    Ad = erdos_renyi_matrix(jax.random.PRNGKey(6), 256, 64, 0.02)
+    srt = blocksparse.blockify(Ad, 1, 1).sort_rows(align=SORT_ALIGN)
+    assert srt.unit_rows == 16
+    offs = np.asarray(srt.row_offsets[0, 0])
+    per_tile = offs[8::8] - offs[:-8:8]            # nonzeros per 8 rows
+    narrow = int((-(-per_tile // SORT_ALIGN)).sum()) * SORT_ALIGN
+    assert srt.vals.shape[-1] < narrow
+    rows, tiles = np.asarray(srt.rows[0, 0]), np.asarray(srt.row_tiles[0, 0])
+    valid = np.asarray(srt.row_valid[0, 0])
+    for u, t in enumerate(tiles):
+        seg = rows[u * SORT_ALIGN:u * SORT_ALIGN + valid[u]]
+        assert ((seg >= t * 8) & (seg < t * 8 + srt.unit_rows)).all()
+    B = jax.random.normal(KEY, (64, 5))
+    C = jax.random.normal(jax.random.fold_in(KEY, 1), (256, 5))
+    Ad = np.asarray(Ad, np.float32)
+    np.testing.assert_allclose(
+        np.asarray(blocksparse.local_spmm(srt, B, impl="sorted")),
+        Ad @ np.asarray(B), atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(blocksparse.local_spmm_t(srt, C, impl="sorted")),
+        Ad.T @ np.asarray(C), atol=1e-5)
+
+
+def test_unit_rows_for_sparse_phase_block():
+    """The compiled kernel's align (1,024) on the one-chip sparse block
+    (43,200 × 28,800 at density 1e-3) gives 64-row units: ~1,843 nonzeros
+    a unit in the sparser orientation, where an 8-row tile holds ~230."""
+    assert blocksparse.unit_rows_for(1_243_546, 43_200, 28_800, 1024) == 64
+    assert blocksparse.unit_rows_for(10 ** 9, 100, 100, 1024) == 8
+    assert blocksparse.unit_rows_for(0, 100, 100, 1024) == \
+        blocksparse.MAX_UNIT_ROWS
 
 
 def test_sorted_requires_metadata():
