@@ -34,6 +34,7 @@ import jax.numpy as jnp
 
 from repro.backends.base import LocalOps
 from repro.core import blocksparse
+from repro.obs import trace as _trace
 
 _IMPLS = ("auto", "scatter", "pallas", "sorted")
 
@@ -95,7 +96,8 @@ class SparseOps(LocalOps):
                 and (blk.has_sorted_rows or not need_rows)
                 and (blk.has_sorted_cols or not need_cols)):
             return blk
-        return blk.sort_rows(align=self.align, orient=orient)
+        with _trace.span("nmf.sort_rows"):
+            return blk.sort_rows(align=self.align, orient=orient)
 
     # -- products -----------------------------------------------------------
 

@@ -75,21 +75,34 @@ def aunmf_step_rule(A, W, H, rule, state, normA_sq, *,
     supplies them from the selected backend.  None falls back to plain XLA
     (with the BCOO-aware default for sparse A: (AᵀW)ᵀ keeps A
     un-transposed) for direct callers.
+
+    Each phase runs under ``jax.named_scope`` with its key from
+    ``repro.obs.phases.expected_phases("serial")`` (``gram_w``, ``mm_w``,
+    ``luc_w``, ..., ``error``), so every op of the compiled step names
+    its phase in its HLO ``op_name`` and in a device profile.
     """
-    HHt = gram(H.T) if gram is not None else H @ H.T
-    AHt = mm(A, H.T) if mm is not None else A @ H.T
-    W, state = rule.update_w(HHt, AHt, W, state, norm_psum=norm_psum)
-    WtW = gram(W) if gram is not None else W.T @ W
-    if mm_t is not None:
-        WtA = mm_t(A, W).T
-    elif isinstance(A, jax.Array):
-        WtA = W.T @ A
-    else:  # BCOO: (Aᵀ W)ᵀ via transposed matvec path
-        WtA = (A.T @ W).T
-    Ht, state = rule.update_h(WtW, WtA.T, H.T, state, norm_psum=norm_psum)
-    H = Ht.T
-    HHt_new = gram(H.T) if gram is not None else H @ H.T
-    sq = sq_error_from_products(normA_sq, WtA, H, WtW, HHt_new)
+    with jax.named_scope("gram_w"):
+        HHt = gram(H.T) if gram is not None else H @ H.T
+    with jax.named_scope("mm_w"):
+        AHt = mm(A, H.T) if mm is not None else A @ H.T
+    with jax.named_scope("luc_w"):
+        W, state = rule.update_w(HHt, AHt, W, state, norm_psum=norm_psum)
+    with jax.named_scope("gram_h"):
+        WtW = gram(W) if gram is not None else W.T @ W
+    with jax.named_scope("mm_h"):
+        if mm_t is not None:
+            WtA = mm_t(A, W).T
+        elif isinstance(A, jax.Array):
+            WtA = W.T @ A
+        else:  # BCOO: (Aᵀ W)ᵀ via transposed matvec path
+            WtA = (A.T @ W).T
+    with jax.named_scope("luc_h"):
+        Ht, state = rule.update_h(WtW, WtA.T, H.T, state,
+                                  norm_psum=norm_psum)
+        H = Ht.T
+    with jax.named_scope("error"):
+        HHt_new = gram(H.T) if gram is not None else H @ H.T
+        sq = sq_error_from_products(normA_sq, WtA, H, WtW, HHt_new)
     return W, H, sq, state
 
 

@@ -51,7 +51,8 @@ class _BPPState(NamedTuple):
     alpha: jax.Array    # (r,) int32 remaining full-exchange credits
     beta: jax.Array     # (r,) int32 best (smallest) infeasible count seen
     done: jax.Array     # (r,) bool
-    it: jax.Array       # () int32
+    it: jax.Array       # () int32 rounds run
+    row_rounds: jax.Array  # () int32 rows kept from each round, summed
 
 
 def _masked_solve(G: jax.Array, passive: jax.Array, rhs: jax.Array,
@@ -130,9 +131,23 @@ def solve_bpp(G: jax.Array, R: jax.Array, *, max_iter: int | None = None,
     Returns:
       X: (r, k) with X >= 0, KKT-optimal per row (up to fp tolerance).
       Rows are solved in chunks of ``ROW_CHUNK``, one after another.
-      With ``counts``, ``(X, {"regularised_rows", "clamped_rows"})``:
-      int32 counts of the rows re-solved with a Tikhonov term
-      (``ESCALATION``) and of those left off a KKT point even then.
+      With ``counts``, ``(X, counts)``, where ``counts`` holds:
+
+      * ``regularised_rows``, ``clamped_rows``: int32 counts of the rows
+        re-solved with a Tikhonov term (``ESCALATION``) and of those left
+        off a KKT point even then;
+      * ``pivot_rounds``: int32, the rounds the pivoting loop ran, summed
+        over the row chunks and the escalation passes that ran;
+      * ``pivot_row_rounds``: float32, the row-solves those rounds needed:
+        per round, the rows still off their KKT point at its start (the
+        rows whose new iterate the round keeps).  Each round computes a
+        solve for every row of its chunk, so ``pivot_row_rounds ÷
+        (pivot_rounds × rows per chunk)`` is the share of that work that
+        was needed.  float32 because a half-step of the 126,675 × 13,824
+        video block can add up to ~1e8 (16 chunks × 8,192 rows × 260
+        rounds × 3 passes), so an int32 sum could overflow within a short
+        fit; float32 is exact to 2^24 and rounds at 6e-8 relative
+        beyond.
     """
     r, k = R.shape
     if max_iter is None:
@@ -150,26 +165,32 @@ def solve_bpp(G: jax.Array, R: jax.Array, *, max_iter: int | None = None,
     ridge = lambda rho: rho * jnp.diag(G) + finfo.tiny
 
     def solve(Rb):
-        x, done = _solve_rows(G, Rb, ridge(k * finfo.eps), max_iter)
+        x, done, work = _solve_rows(G, Rb, ridge(k * finfo.eps), max_iter)
         regularised = _missed(done)
         for rho in ESCALATION:
             def retry(state, rho=rho):
-                x, done = state
-                x2, done2 = _solve_rows(G, Rb, ridge(rho), max_iter, done)
-                return jnp.where(done[:, None], x, x2), done | done2
-            x, done = lax.cond(jnp.all(done), lambda s: s, retry, (x, done))
-        return jnp.maximum(x, 0.0), jnp.stack([regularised, _missed(done)])
+                x, done, work = state
+                x2, done2, work2 = _solve_rows(G, Rb, ridge(rho), max_iter,
+                                               done)
+                return (jnp.where(done[:, None], x, x2), done | done2,
+                        work + work2)
+            x, done, work = lax.cond(jnp.all(done), lambda s: s, retry,
+                                     (x, done, work))
+        rows = jnp.stack([regularised, _missed(done), work[0]])
+        return jnp.maximum(x, 0.0), rows, work[1].astype(jnp.float32)
 
     if r <= ROW_CHUNK:
-        X, c = solve(R)
+        X, c, row_rounds = solve(R)
     else:
         # Zero right-hand sides are optimal at x = 0 and finish at once.
         Rc = jnp.pad(R, ((0, (-r) % ROW_CHUNK), (0, 0))).reshape(
             -1, ROW_CHUNK, k)
-        X, c = lax.map(solve, Rc)
-        X, c = X.reshape(-1, k)[:r], c.sum(axis=0)
+        X, c, row_rounds = lax.map(solve, Rc)
+        X, c, row_rounds = X.reshape(-1, k)[:r], c.sum(axis=0), \
+            row_rounds.sum()
     if counts:
-        return X, {"regularised_rows": c[0], "clamped_rows": c[1]}
+        return X, {"regularised_rows": c[0], "clamped_rows": c[1],
+                   "pivot_rounds": c[2], "pivot_row_rounds": row_rounds}
     return X
 
 
@@ -179,66 +200,78 @@ def _missed(done: jax.Array) -> jax.Array:
 
 def _solve_rows(G: jax.Array, R: jax.Array, ridge: jax.Array,
                 max_iter: int, skip: jax.Array | None = None
-                ) -> tuple[jax.Array, jax.Array]:
+                ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """The pivoting loop over one batch of right-hand sides (rows in
-    ``skip`` are left at zero); returns the iterate and which rows reached
-    a KKT point."""
+    ``skip`` are left at zero); returns the iterate, which rows reached
+    a KKT point, and the loop's work as int32 ``[rounds, row-solves]``:
+    the rounds it ran and, summed over them, the rows off their KKT point
+    at the start of each (those whose new iterate the round keeps)."""
+    def cond(st: _BPPState) -> jax.Array:
+        return (~jnp.all(st.done)) & (st.it < max_iter)
+
+    st = lax.while_loop(cond, functools.partial(_pivot_round, G, R, ridge),
+                        _initial_state(R, skip))
+    return (st.x, st.done | ~jnp.any(_infeasible(st), axis=1),
+            jnp.stack([st.it, st.row_rounds]))
+
+
+def _initial_state(R: jax.Array, skip: jax.Array | None = None) -> _BPPState:
     r, k = R.shape
-    dtype = R.dtype
-    init = _BPPState(
-        x=jnp.zeros((r, k), dtype),
+    return _BPPState(
+        x=jnp.zeros((r, k), R.dtype),
         y=-R,                                        # y = G·0 − r
         passive=jnp.zeros((r, k), bool),
         alpha=jnp.full((r,), 3, jnp.int32),
         beta=jnp.full((r,), k + 1, jnp.int32),
         done=jnp.all(-R >= 0, axis=1) | (False if skip is None else skip),
         it=jnp.zeros((), jnp.int32),
+        row_rounds=jnp.zeros((), jnp.int32),
     )
 
-    tol = jnp.asarray(0.0, dtype)  # strict sign tests, as in the reference code
 
-    def infeasible(st: _BPPState) -> jax.Array:
-        return (st.passive & (st.x < -tol)) | (~st.passive & (st.y < -tol))
+def _infeasible(st: _BPPState) -> jax.Array:
+    # Strict sign tests, as in the reference code.
+    return (st.passive & (st.x < 0)) | (~st.passive & (st.y < 0))
 
-    def cond(st: _BPPState) -> jax.Array:
-        return (~jnp.all(st.done)) & (st.it < max_iter)
 
-    def body(st: _BPPState) -> _BPPState:
-        V = infeasible(st)                           # (r, k)
-        ninf = jnp.sum(V, axis=1).astype(jnp.int32)  # (r,)
-        col_done = ninf == 0
+def _pivot_round(G: jax.Array, R: jax.Array, ridge: jax.Array,
+                 st: _BPPState) -> _BPPState:
+    """One round of the pivoting loop over every row of the batch."""
+    k = R.shape[1]
+    V = _infeasible(st)                              # (r, k)
+    ninf = jnp.sum(V, axis=1).astype(jnp.int32)      # (r,)
+    col_done = ninf == 0
 
-        improved = ninf < st.beta
-        use_full = improved | (st.alpha > 0)
-        new_beta = jnp.where(improved, ninf, st.beta)
-        new_alpha = jnp.where(improved, 3, jnp.where(use_full, st.alpha - 1, st.alpha))
+    improved = ninf < st.beta
+    use_full = improved | (st.alpha > 0)
+    new_beta = jnp.where(improved, ninf, st.beta)
+    new_alpha = jnp.where(improved, 3, jnp.where(use_full, st.alpha - 1, st.alpha))
 
-        # Backup rule: flip only the largest infeasible index.
-        idx = jnp.arange(k)[None, :]
-        largest = jnp.max(jnp.where(V, idx, -1), axis=1)    # (r,)
-        single = idx == largest[:, None]
-        flip = jnp.where(use_full[:, None], V, V & single)
+    # Backup rule: flip only the largest infeasible index.
+    idx = jnp.arange(k)[None, :]
+    largest = jnp.max(jnp.where(V, idx, -1), axis=1)    # (r,)
+    single = idx == largest[:, None]
+    flip = jnp.where(use_full[:, None], V, V & single)
 
-        passive = st.passive ^ flip
-        x = _masked_solve(G, passive, R, ridge)
-        y = dot(x, G, ((1,), (1,))).astype(dtype) - R
-        y = jnp.where(passive, 0.0, y)
-        x = jnp.where(passive, x, 0.0)
+    passive = st.passive ^ flip
+    x = _masked_solve(G, passive, R, ridge)
+    y = dot(x, G, ((1,), (1,))).astype(R.dtype) - R
+    y = jnp.where(passive, 0.0, y)
+    x = jnp.where(passive, x, 0.0)
 
-        # Freeze finished columns.
-        keep = (st.done | col_done)[:, None]
-        return _BPPState(
-            x=jnp.where(keep, st.x, x),
-            y=jnp.where(keep, st.y, y),
-            passive=jnp.where(keep, st.passive, passive),
-            alpha=jnp.where(st.done | col_done, st.alpha, new_alpha),
-            beta=jnp.where(st.done | col_done, st.beta, new_beta),
-            done=st.done | col_done,
-            it=st.it + 1,
-        )
-
-    st = lax.while_loop(cond, body, init)
-    return st.x, st.done | ~jnp.any(infeasible(st), axis=1)
+    # Freeze finished columns.
+    done = st.done | col_done
+    keep = done[:, None]
+    return _BPPState(
+        x=jnp.where(keep, st.x, x),
+        y=jnp.where(keep, st.y, y),
+        passive=jnp.where(keep, st.passive, passive),
+        alpha=jnp.where(done, st.alpha, new_alpha),
+        beta=jnp.where(done, st.beta, new_beta),
+        done=done,
+        it=st.it + 1,
+        row_rounds=st.row_rounds + _missed(done),
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("max_iter",))

@@ -73,6 +73,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro import backends as _backends
 from repro.core import rules as _rules
 from repro.core.aunmf import NMFResult, aunmf_step_rule, init_h, init_w
+from repro.obs import trace as _trace
 from repro.util.compat import make_mesh
 
 SCHEDULES = ("serial", "faun", "naive", "gspmd")
@@ -667,30 +668,33 @@ class NMFSolver:
         prepare path.  Explicit ``W0``/``H0`` are installed untouched —
         this is the bit-identical resume path; ``init=`` warm starts go
         through the same eps-flooring as ``fit(init=...)``."""
-        m, n = A.shape
-        dtype = getattr(A, "dtype", jnp.float32)
-        # Rules that size themselves from the problem (inner_iters=None)
-        # specialise here, where the global dims are first known; the
-        # prepared rule feeds the run-cache key, so shape changes recompile.
-        self.rule = self._base_rule.prepare_global(m, n, self.k)
-        if init is not None:
-            if H0 is not None or W0 is not None:
-                raise ValueError("pass either init= (a warm start) or "
-                                 "explicit W0/H0, not both")
-            W0, H0 = _warm_start_factors(init, m, n, self.k, dtype,
-                                         self.rule)
-        used_key = None
-        if H0 is None or W0 is None:
-            used_key = jax.random.PRNGKey(0) if key is None else key
-        if H0 is None:
-            H0 = init_h(used_key, n, self.k, dtype=dtype)
-        if W0 is None:
-            W0 = init_w(jax.random.fold_in(used_key, 1), m, self.k,
-                        self.rule, dtype=dtype)
-        Arep, W, Ht, normA_sq = self._schedule.prepare(A, W0, H0)
-        state0 = self._schedule.init_carry(m, n, dtype)
-        return RunState(Arep=Arep, W=W, Ht=Ht, normA_sq=normA_sq,
-                        state=state0, m=m, n=n, dtype=dtype, key=used_key)
+        with _trace.span("nmf.prepare_state"):
+            m, n = A.shape
+            dtype = getattr(A, "dtype", jnp.float32)
+            # Rules that size themselves from the problem
+            # (inner_iters=None) specialise here, where the global dims are
+            # first known; the prepared rule feeds the run-cache key, so
+            # shape changes recompile.
+            self.rule = self._base_rule.prepare_global(m, n, self.k)
+            if init is not None:
+                if H0 is not None or W0 is not None:
+                    raise ValueError("pass either init= (a warm start) or "
+                                     "explicit W0/H0, not both")
+                W0, H0 = _warm_start_factors(init, m, n, self.k, dtype,
+                                             self.rule)
+            used_key = None
+            if H0 is None or W0 is None:
+                used_key = jax.random.PRNGKey(0) if key is None else key
+            if H0 is None:
+                H0 = init_h(used_key, n, self.k, dtype=dtype)
+            if W0 is None:
+                W0 = init_w(jax.random.fold_in(used_key, 1), m, self.k,
+                            self.rule, dtype=dtype)
+            with _trace.span("nmf.layout"):
+                Arep, W, Ht, normA_sq = self._schedule.prepare(A, W0, H0)
+            state0 = self._schedule.init_carry(m, n, dtype)
+            return RunState(Arep=Arep, W=W, Ht=Ht, normA_sq=normA_sq,
+                            state=state0, m=m, n=n, dtype=dtype, key=used_key)
 
     def run_segment(self, rs: RunState, iters: int) -> RunState:
         """Advance ``iters`` fixed iterations in place.  Segments re-enter
@@ -700,13 +704,17 @@ class NMFSolver:
         the property the elastic checkpoint/restore tests assert."""
         if iters <= 0:
             return rs
-        run = _cached_run(self._schedule, StoppingCriterion(max_iters=iters),
-                          self.donate)
-        W, Ht, rels, state = run(rs.Arep, rs.W, rs.Ht, rs.normA_sq,
-                                 rs.state, iters)
-        rs.W, rs.Ht, rs.state = W, Ht, state
-        rs.step += iters
-        rs.rel_history.append(jax.device_get(rels))
+        with _trace.span("nmf.segment"):
+            with _trace.span("nmf.dispatch"):
+                run = _cached_run(self._schedule,
+                                  StoppingCriterion(max_iters=iters),
+                                  self.donate)
+                W, Ht, rels, state = run(rs.Arep, rs.W, rs.Ht, rs.normA_sq,
+                                         rs.state, iters)
+            rs.W, rs.Ht, rs.state = W, Ht, state
+            rs.step += iters
+            with _trace.span("nmf.fetch_errors"):
+                rs.rel_history.append(jax.device_get(rels))
         return rs
 
     def restore_carry(self, rs: RunState, *, rule_state=None,
@@ -876,7 +884,8 @@ def _fixed_run(step, donate: bool):
             # Backends may emit fp32 from low-precision factors (fp32
             # accumulation); restore the carry dtype (no-op for fp32 runs).
             W, Ht = Wn.astype(W.dtype), Htn.astype(Ht.dtype)
-            rel = jnp.sqrt(jnp.maximum(sq, 0.0) / normA_sq)
+            with jax.named_scope("error"):
+                rel = jnp.sqrt(jnp.maximum(sq, 0.0) / normA_sq)
             return (W, Ht, state), rel
 
         (W, Ht, state), rels = lax.scan(body, (W, Ht, state), None,
@@ -900,7 +909,8 @@ def _adaptive_run(step, crit: StoppingCriterion, donate: bool):
             W, Ht, rels, i, best, stall, _, rstate = state
             Wn, Htn, sq, rstate = step(Arep, W, Ht, normA_sq, rstate)
             W, Ht = Wn.astype(W.dtype), Htn.astype(Ht.dtype)
-            rel = jnp.sqrt(jnp.maximum(sq, 0.0) / normA_sq)
+            with jax.named_scope("error"):
+                rel = jnp.sqrt(jnp.maximum(sq, 0.0) / normA_sq)
             rels = lax.dynamic_update_index_in_dim(rels, rel, i, 0)
             improved = rel < best - stall_tol
             stall = jnp.where(improved, 0, stall + 1)

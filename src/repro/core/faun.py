@@ -172,31 +172,53 @@ def faun_iteration(A_blk, W_blk, Ht_blk, normA_sq, state, *, row_axes,
             y, res[key] = compress.reduce_scatter(x, tuple(axes), res[key])
             return y
 
+    # Each phase under its ``repro.obs.phases.expected_phases("faun")``
+    # key, so every op of the compiled step names its phase.
+    scope = jax.named_scope
+
     # ---- W given H (paper lines 3–8) ----
-    HHt = panel_allreduce(gram(Ht_blk), all_axes, "gram_w")       # k×k
-    # Gather innermost-axis first (multi-pod finishes across pods).
-    Hj_t = panel_allgather(Ht_blk, tuple(reversed(row_axes)), "gather_h")
-    V = mm(cast(A_blk), Hj_t)                                     # (m/prE, k)
-    AHt_blk = panel_reduce_scatter(V, (col_axis,), "rs_w")        # (m/p, k)
-    W_blk, state = rule.update_w(HHt, AHt_blk, W_blk, state,
-                                 norm_psum=norm_psum)
+    with scope("gram_w"):
+        U = gram(Ht_blk)
+    with scope("allreduce_gram_w"):
+        HHt = panel_allreduce(U, all_axes, "gram_w")              # k×k
+    with scope("allgather_h"):
+        # Gather innermost-axis first (multi-pod finishes across pods).
+        Hj_t = panel_allgather(Ht_blk, tuple(reversed(row_axes)),
+                               "gather_h")
+    with scope("mm_w"):
+        V = mm(cast(A_blk), Hj_t)                                 # (m/prE, k)
+    with scope("reduce_scatter_w"):
+        AHt_blk = panel_reduce_scatter(V, (col_axis,), "rs_w")    # (m/p, k)
+    with scope("luc_w"):
+        W_blk, state = rule.update_w(HHt, AHt_blk, W_blk, state,
+                                     norm_psum=norm_psum)
 
     # ---- H given W (paper lines 9–14) ----
-    WtW = panel_allreduce(gram(W_blk), all_axes, "gram_h")        # k×k
-    Wi = panel_allgather(W_blk, (col_axis,), "gather_w")          # (m/prE, k)
-    Yt = mm_t(cast(A_blk), Wi)                                    # (n/pc, k)
-    # Scatter outer-to-inner (pod, then pr) to land in the (pc,pod,pr) layout.
-    WtA_t_blk = panel_reduce_scatter(Yt, tuple(row_axes), "rs_h")
-    Ht_blk, state = rule.update_h(WtW, WtA_t_blk, Ht_blk, state,
-                                  norm_psum=norm_psum)
+    with scope("gram_h"):
+        U = gram(W_blk)
+    with scope("allreduce_gram_h"):
+        WtW = panel_allreduce(U, all_axes, "gram_h")              # k×k
+    with scope("allgather_w"):
+        Wi = panel_allgather(W_blk, (col_axis,), "gather_w")      # (m/prE, k)
+    with scope("mm_h"):
+        Yt = mm_t(cast(A_blk), Wi)                                # (n/pc, k)
+    with scope("reduce_scatter_h"):
+        # Scatter outer-to-inner (pod, then pr) to land in the
+        # (pc,pod,pr) layout.
+        WtA_t_blk = panel_reduce_scatter(Yt, tuple(row_axes), "rs_h")
+    with scope("luc_h"):
+        Ht_blk, state = rule.update_h(WtW, WtA_t_blk, Ht_blk, state,
+                                      norm_psum=norm_psum)
 
     # ---- relative error from byproducts (one extra k×k Gram) ----
-    HHt_new = lax.psum(gram(Ht_blk), all_axes)
-    cross = lax.psum(
-        jnp.sum(WtA_t_blk.astype(jnp.float32) * Ht_blk.astype(jnp.float32)),
-        all_axes)
-    quad = jnp.sum(WtW.astype(jnp.float32) * HHt_new.astype(jnp.float32))
-    sq_err = normA_sq - 2.0 * cross + quad
+    with scope("error"):
+        HHt_new = lax.psum(gram(Ht_blk), all_axes)
+        cross = lax.psum(
+            jnp.sum(WtA_t_blk.astype(jnp.float32)
+                    * Ht_blk.astype(jnp.float32)), all_axes)
+        quad = jnp.sum(WtW.astype(jnp.float32)
+                       * HHt_new.astype(jnp.float32))
+        sq_err = normA_sq - 2.0 * cross + quad
     if compress is not None:
         state = (state, {key: v.reshape((1,) * len(all_axes) + v.shape)
                          for key, v in res.items()})

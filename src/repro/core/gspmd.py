@@ -52,20 +52,32 @@ def gspmd_iteration(A, W, Ht, normA_sq, state, *, algo, ops=None,
     res = None
     if compress is not None:
         state, res = state[0], dict(state[1])
-    H = Ht.T
-    HHt = ops.gram(Ht)
-    AHt = ops.mm(A, H.T)
-    if compress is not None:
-        HHt, res["gram_w"] = compress.simulate_gram(HHt, res["gram_w"])
-        AHt, res["rs_w"] = compress.simulate(AHt, res["rs_w"])
-    W, state = rule.update_w(HHt, AHt, W, state)
-    WtW = ops.gram(W)
-    WtA_t = ops.mm_t(A, W)
-    if compress is not None:
-        WtW, res["gram_h"] = compress.simulate_gram(WtW, res["gram_h"])
-        WtA_t, res["rs_h"] = compress.simulate(WtA_t, res["rs_h"])
-    Ht, state = rule.update_h(WtW, WtA_t, Ht, state)
-    sq = sq_error_from_products(normA_sq, WtA_t.T, Ht.T, WtW, ops.gram(Ht))
+    # Each phase under its ``repro.obs.phases.expected_phases("gspmd")``
+    # key; the collectives XLA inserts take the phase that forced them.
+    scope = jax.named_scope
+    with scope("gram_w"):
+        HHt = ops.gram(Ht)
+        if compress is not None:
+            HHt, res["gram_w"] = compress.simulate_gram(HHt, res["gram_w"])
+    with scope("mm_w"):
+        AHt = ops.mm(A, Ht)
+        if compress is not None:
+            AHt, res["rs_w"] = compress.simulate(AHt, res["rs_w"])
+    with scope("luc_w"):
+        W, state = rule.update_w(HHt, AHt, W, state)
+    with scope("gram_h"):
+        WtW = ops.gram(W)
+        if compress is not None:
+            WtW, res["gram_h"] = compress.simulate_gram(WtW, res["gram_h"])
+    with scope("mm_h"):
+        WtA_t = ops.mm_t(A, W)
+        if compress is not None:
+            WtA_t, res["rs_h"] = compress.simulate(WtA_t, res["rs_h"])
+    with scope("luc_h"):
+        Ht, state = rule.update_h(WtW, WtA_t, Ht, state)
+    with scope("error"):
+        sq = sq_error_from_products(normA_sq, WtA_t.T, Ht.T, WtW,
+                                    ops.gram(Ht))
     if compress is not None:
         state = (state, res)
     return W, Ht, sq, state

@@ -64,26 +64,40 @@ def naive_iteration(Arow, Acol, W_blk, Ht_blk, normA_sq, state, *, axis: str,
         y, res[key] = compress.all_gather(x, (axis,), res[key])
         return y
 
+    # Each phase under its ``repro.obs.phases.expected_phases("naive")``
+    # key, so every op of the compiled step names its phase.
+    scope = jax.named_scope
+
     # --- W given H: all-gather whole H, redundant Gram (paper lines 3-4) ---
-    Ht = panel_allgather(Ht_blk, "gather_h")                  # (n, k)
-    HHt = ops.gram(Ht)                                        # redundant k×k
-    AHt_blk = ops.mm(Arow, Ht)                                # (m/p, k)
-    W_blk, state = rule.update_w(HHt, AHt_blk, W_blk, state,
-                                 norm_psum=norm_psum)
+    with scope("allgather_h"):
+        Ht = panel_allgather(Ht_blk, "gather_h")              # (n, k)
+    with scope("gram_w"):
+        HHt = ops.gram(Ht)                                    # redundant k×k
+    with scope("mm_w"):
+        AHt_blk = ops.mm(Arow, Ht)                            # (m/p, k)
+    with scope("luc_w"):
+        W_blk, state = rule.update_w(HHt, AHt_blk, W_blk, state,
+                                     norm_psum=norm_psum)
 
     # --- H given W: all-gather whole W, redundant Gram (lines 5-6) ---
-    W = panel_allgather(W_blk, "gather_w")                    # (m, k)
-    WtW = ops.gram(W)
-    WtA_t_blk = ops.mm_t(Acol, W)                             # (n/p, k)
-    Ht_blk, state = rule.update_h(WtW, WtA_t_blk, Ht_blk, state,
-                                  norm_psum=norm_psum)
+    with scope("allgather_w"):
+        W = panel_allgather(W_blk, "gather_w")                # (m, k)
+    with scope("gram_h"):
+        WtW = ops.gram(W)
+    with scope("mm_h"):
+        WtA_t_blk = ops.mm_t(Acol, W)                         # (n/p, k)
+    with scope("luc_h"):
+        Ht_blk, state = rule.update_h(WtW, WtA_t_blk, Ht_blk, state,
+                                      norm_psum=norm_psum)
 
     # --- error from byproducts ---
-    HHt_new = lax.psum(ops.gram(Ht_blk), axis)
-    cross = lax.psum(jnp.sum(WtA_t_blk.astype(jnp.float32)
-                             * Ht_blk.astype(jnp.float32)), axis)
-    quad = jnp.sum(WtW.astype(jnp.float32) * HHt_new.astype(jnp.float32))
-    sq_err = normA_sq - 2.0 * cross + quad
+    with scope("error"):
+        HHt_new = lax.psum(ops.gram(Ht_blk), axis)
+        cross = lax.psum(jnp.sum(WtA_t_blk.astype(jnp.float32)
+                                 * Ht_blk.astype(jnp.float32)), axis)
+        quad = jnp.sum(WtW.astype(jnp.float32)
+                       * HHt_new.astype(jnp.float32))
+        sq_err = normA_sq - 2.0 * cross + quad
     if compress is not None:
         state = (state, {key: v[None] for key, v in res.items()})
     return W_blk, Ht_blk, sq_err, state

@@ -406,12 +406,17 @@ class BPPRule(UpdateRule):
         self.max_iter = max_iter
 
     def init_state(self, m, n, k, dtype=jnp.float32):
-        """Rows of the fit's half-steps that BPP re-solved with a Tikhonov
-        term, and rows it clamped (``core.bpp.ESCALATION``), summed over
-        the grid and the iterations."""
+        """``solve_bpp``'s counts, summed over the grid and the iterations:
+        rows of the fit's half-steps that BPP re-solved with a Tikhonov
+        term, rows it clamped (``core.bpp.ESCALATION``), pivoting rounds
+        (int32) and the row-solves they needed (``pivot_row_rounds``,
+        float32: an int32 sum overflows within a short fit on the video
+        block)."""
         del m, n, k, dtype
         zero = jnp.zeros((), jnp.int32)
-        return {"regularised_rows": zero, "clamped_rows": zero}
+        return {"regularised_rows": zero, "clamped_rows": zero,
+                "pivot_rounds": zero,
+                "pivot_row_rounds": jnp.zeros((), jnp.float32)}
 
     def _update_w(self, G, R, X, state, *, norm_psum):
         X, counts = solve_bpp(G, R, max_iter=self.max_iter, counts=True)

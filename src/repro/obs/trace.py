@@ -16,10 +16,16 @@ positional — Perfetto stacks spans on the same thread by containment, so a
 ``with`` inside a ``with`` renders as a child without any bookkeeping here
 beyond per-thread timing.
 
-The serve/online layers emit spans through the PROCESS-DEFAULT tracer
-(``default_tracer()``), which starts disabled: ``span()`` on a disabled
-tracer is a shared no-op context manager, so instrumented hot paths cost
-one attribute check when nobody is tracing.  ``default_tracer().enable()``
+While a tracer is enabled, each span also opens a
+``jax.profiler.TraceAnnotation`` of its name, so a JAX profiler trace taken
+meanwhile (``jax.profiler.start_trace``) holds the span on its host plane,
+on the same clock as the device's ops.
+
+The serve/online layers and the NMF engine (``nmf.*`` spans) emit spans
+through the PROCESS-DEFAULT tracer (``default_tracer()``), which starts
+disabled: ``span()`` on a disabled tracer is a shared no-op context
+manager, so instrumented hot paths cost one attribute check when nobody
+is tracing.  ``default_tracer().enable()``
 (or constructing your own ``Tracer`` and passing it where accepted) turns
 collection on.  The event buffer is bounded (``max_events``); overflow
 drops new events and counts them in ``dropped`` rather than growing
@@ -33,6 +39,8 @@ import threading
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass
+
+import jax.profiler
 
 
 @dataclass(frozen=True)
@@ -88,16 +96,20 @@ class Tracer:
 
     @contextmanager
     def _span_cm(self, name: str, args: tuple):
-        t0 = time.perf_counter()
-        try:
-            yield self
-        finally:
-            t1 = time.perf_counter()
-            self.record(name, t0, t1, args)
+        with jax.profiler.TraceAnnotation(name):
+            t0 = time.perf_counter()
+            try:
+                yield self
+            finally:
+                t1 = time.perf_counter()
+                self.record(name, t0, t1, args)
 
     def span(self, name: str, **args):
         """Context manager timing one span; ``args`` land in the trace
-        viewer's detail pane.  No-op (and allocation-free) when disabled."""
+        viewer's detail pane.  The span also opens a
+        ``jax.profiler.TraceAnnotation`` of the same name, so while a
+        profiler trace runs it lands on the trace's host plane, on the
+        device ops' clock.  No-op (and allocation-free) when disabled."""
         if not self.enabled:
             return _NULL_SPAN
         return self._span_cm(name, tuple(sorted(args.items())))

@@ -168,8 +168,9 @@ def test_counts_regularised_and_clamped_rows():
     C = jax.random.uniform(key, (80, 6))
     B = jax.random.uniform(jax.random.fold_in(key, 1), (80, 40))
     X, c = solve_bpp(C.T @ C, (C.T @ B).T, counts=True)
-    assert {k: int(v) for k, v in c.items()} == {"regularised_rows": 0,
-                                                 "clamped_rows": 0}
+    rows = ("regularised_rows", "clamped_rows")
+    assert {k: int(c[k]) for k in rows} == {"regularised_rows": 0,
+                                            "clamped_rows": 0}
     np.testing.assert_array_equal(np.asarray(X),
                                   np.asarray(solve_bpp(C.T @ C, (C.T @ B).T)))
     _, c = solve_bpp(*_collinear_problem(), counts=True)
@@ -183,5 +184,70 @@ def test_fit_reports_bpp_row_counts():
     A = jax.random.uniform(jax.random.PRNGKey(4), (40, 30))
     res = NMFSolver(4, algo="bpp", max_iters=3).fit(A)
     st = res.extras["rule_state"]
-    assert set(st) == {"regularised_rows", "clamped_rows"}
+    assert set(st) == {"regularised_rows", "clamped_rows", "pivot_rounds",
+                       "pivot_row_rounds"}
     assert 0 <= int(st["clamped_rows"]) <= int(st["regularised_rows"])
+    assert st["pivot_row_rounds"].dtype == np.float32
+    # Every half-step of every iteration runs at least one round, and a
+    # round keeps at most the 40 (W) or 30 (H) rows of its batch.
+    assert int(st["pivot_rounds"]) >= 6
+    assert 0 < float(st["pivot_row_rounds"]) <= 40 * int(st["pivot_rounds"])
+
+
+def _host_pivot_counts(G, R, max_iter=None):
+    """``solve_bpp``'s pivoting counts from a plain host loop: one jitted
+    round at a time, with the escalation and the row chunks of
+    ``solve_bpp`` spelled out, counting on the host the rows off their KKT
+    point at the start of each round."""
+    from repro.core import bpp
+    r, k = R.shape
+    max_iter = 5 * k + 10 if max_iter is None else max_iter
+    tiny, eps = np.finfo(np.float32).tiny, np.finfo(np.float32).eps
+    one_round = jax.jit(bpp._pivot_round)
+    rounds = row_rounds = 0
+    for lo in range(0, r, bpp.ROW_CHUNK):
+        Rb = R[lo:lo + bpp.ROW_CHUNK]
+        done = None
+        for rho in (k * eps,) + bpp.ESCALATION:
+            if done is not None and done.all():
+                break
+            ridge = rho * jnp.diag(G) + tiny
+            st = bpp._initial_state(Rb, None if done is None
+                                    else jnp.asarray(done))
+            for _ in range(max_iter):
+                if bool(jnp.all(st.done)):
+                    break
+                off = ~(np.asarray(st.done)
+                        | ~np.asarray(bpp._infeasible(st)).any(axis=1))
+                rounds += 1
+                row_rounds += int(off.sum())
+                st = one_round(G, Rb, ridge, st)
+            reached = np.asarray(st.done) | \
+                ~np.asarray(bpp._infeasible(st)).any(axis=1)
+            done = reached if done is None else done | reached
+    return rounds, row_rounds
+
+
+@pytest.mark.parametrize("problem", ["small", "collinear"])
+def test_pivot_counts_match_a_host_loop(problem, monkeypatch):
+    """``pivot_rounds`` and ``pivot_row_rounds`` count what a host loop
+    over the same rounds counts: on a small problem in three row chunks,
+    and on the collinear one, whose cycling rows run to the round cap and
+    through the escalation passes."""
+    from repro.core import bpp
+    if problem == "small":
+        key = jax.random.PRNGKey(5)
+        C = jax.random.uniform(key, (80, 6))
+        B = jax.random.uniform(jax.random.fold_in(key, 1), (80, 40))
+        G, R = C.T @ C, (C.T @ B).T
+        monkeypatch.setattr(bpp, "ROW_CHUNK", 16)
+    else:
+        G, R = _collinear_problem()
+    _, c = solve_bpp(G, R, counts=True)
+    rounds, row_rounds = _host_pivot_counts(G, R)
+    assert c["pivot_row_rounds"].dtype == jnp.float32
+    assert (int(c["pivot_rounds"]), float(c["pivot_row_rounds"])) == \
+        (rounds, row_rounds)
+    assert 0 < row_rounds <= rounds * min(R.shape[0], bpp.ROW_CHUNK)
+    if problem == "collinear":
+        assert rounds > 5 * R.shape[1] + 10     # the cap, then escalation
